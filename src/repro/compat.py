@@ -1,67 +1,24 @@
-"""Version shims for the jax APIs this repo uses that moved across releases.
+"""The jax runtime hooks several modules share: the Auto-axis mesh
+constructor, the compile listener and the persistent compilation cache.
 
-Two surfaces differ between the jax the image ships (0.4.x) and current
-releases (>= 0.5):
-
-  * ``jax.sharding.get_abstract_mesh`` — the public accessor for the
-    ambient abstract mesh does not exist on 0.4.x (the private
-    ``jax._src.mesh.get_abstract_mesh`` returns a different type there).
-    On old jax we report "no mesh context": sharding constraints become
-    no-ops, which is the correct degenerate behaviour on a single device.
-  * ``jax.sharding.AxisType`` / the ``axis_types=`` kwarg of
-    ``jax.make_mesh`` — absent on 0.4.x, where all axes are Auto anyway.
-
-Everything in the repo goes through these two helpers instead of touching
-``jax.sharding`` directly for mesh construction / mesh-context queries.
+The repo targets jax 0.9; everything else calls the jax API directly.
 """
 from __future__ import annotations
 
-import inspect
+import os
+from pathlib import Path
 
 import jax
 
-
-def get_abstract_mesh():
-    """The ambient abstract mesh, or None when unset / unsupported."""
-    fn = getattr(jax.sharding, "get_abstract_mesh", None)
-    if fn is None:
-        return None
-    return fn()
+REPO_ROOT = Path(__file__).resolve().parents[2]
 
 
-def set_mesh(mesh):
-    """Context manager making ``mesh`` ambient: ``jax.sharding.set_mesh`` on
-    new jax; on 0.4.x the Mesh object itself is the context manager."""
-    fn = getattr(jax.sharding, "set_mesh", None)
-    if fn is not None:
-        return fn(mesh)
-    return mesh
-
-
-def shard_map(f, *, mesh, in_specs, out_specs):
-    """``jax.shard_map`` where it exists, else the 0.4.x experimental one.
-    The replication-check kwarg was renamed (check_rep -> check_vma) partway
-    through, so pick whichever the installed signature accepts."""
-    fn = getattr(jax, "shard_map", None)
-    if fn is None:
-        from jax.experimental.shard_map import shard_map as fn
-    params = inspect.signature(fn).parameters
-    kw = {}
-    if "check_vma" in params:
-        kw["check_vma"] = False
-    elif "check_rep" in params:
-        kw["check_rep"] = False
-    return fn(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs, **kw)
-
-
-def make_mesh(axis_shapes, axis_names, *, explicit: bool = False):
-    """``jax.make_mesh`` with Auto (or Explicit) axis types where supported."""
-    axis_type = getattr(jax.sharding, "AxisType", None)
-    if axis_type is None:
-        return jax.make_mesh(axis_shapes, axis_names)
-    kind = axis_type.Explicit if explicit else axis_type.Auto
-    return jax.make_mesh(axis_shapes, axis_names,
-                         axis_types=(kind,) * len(axis_names))
+def make_mesh(axis_shapes, axis_names):
+    """``jax.make_mesh`` with Auto axes (jax 0.9 defaults to Explicit), so
+    GSPMD propagates shardings the way the sharding rules expect."""
+    return jax.make_mesh(
+        axis_shapes, axis_names,
+        axis_types=(jax.sharding.AxisType.Auto,) * len(axis_names))
 
 
 def register_compile_listener(callback):
@@ -72,61 +29,38 @@ def register_compile_listener(callback):
 
     Rides ``jax.monitoring``'s duration events, filtering to the actual
     backend compile (ignoring the trace/lowering sub-events, which fire
-    per jaxpr and would triple-count).  Returns an *unregister* callable,
-    or None when this jax has no monitoring surface — callers treat
-    compile telemetry as best-effort either way.  Unregistration goes
-    through the private ``jax._src.monitoring`` API when the public one
-    (newer jax) is absent; failure to unregister leaves a listener whose
-    callback is a no-op after ``RunTelemetry.close``, which is harmless.
+    per jaxpr and would triple-count).  Returns the *unregister* callable.
     """
-    try:
-        from jax import monitoring
-    except ImportError:
-        return None
-    if not hasattr(monitoring, "register_event_duration_secs_listener"):
-        return None
-
     def _listener(event, duration, **kwargs):
         if event.endswith("backend_compile_duration"):
             callback(event, duration)
 
-    monitoring.register_event_duration_secs_listener(_listener)
-
-    def _unregister():
-        try:
-            from jax._src import monitoring as _mi
-            _mi._unregister_event_duration_listener_by_callback(_listener)
-        except Exception:
-            pass
-
-    return _unregister
+    jax.monitoring.register_event_duration_secs_listener(_listener)
+    return lambda: jax.monitoring.unregister_event_duration_listener(
+        _listener)
 
 
-def enable_compilation_cache(path) -> bool:
-    """Point jax's persistent compilation cache at ``path`` (created if
-    missing), so a process restart reuses yesterday's XLA executables
-    instead of recompiling — the production-restart half of the paper's
-    compilation-cost protocol (``benchmarks/compile_time.py`` pins the
-    win; the resize cycle in ``benchmarks/elastic_resize.py`` is
-    compile-dominated, which is exactly what this amortizes).
+def compilation_cache_dir() -> Path:
+    """Where the persistent compilation cache lives.
 
-    The knobs moved across releases: the dir config is stable, but the
-    min-compile-time / min-entry-size thresholds (which default to
-    skipping the small CPU executables this repo compiles) appeared later
-    — each is applied best-effort.  Returns True when the cache dir was
-    accepted, False when this jax has no persistent cache at all.
+    ``JAX_COMPILATION_CACHE_DIR``, when set and not empty, is the directory
+    and no other is used.  Otherwise the cache lives at
+    ``<repo>/.jax_cache`` — a fixed path, because the path is part of the
+    cache key and a directory that moves never hits.
     """
-    import os
+    return Path(os.environ.get("JAX_COMPILATION_CACHE_DIR")
+                or REPO_ROOT / ".jax_cache")
 
-    os.makedirs(str(path), exist_ok=True)
-    try:
-        jax.config.update("jax_compilation_cache_dir", str(path))
-    except AttributeError:
-        return False
-    for knob, value in (("jax_persistent_cache_min_compile_time_secs", 0),
-                        ("jax_persistent_cache_min_entry_size_bytes", -1)):
-        try:
-            jax.config.update(knob, value)
-        except AttributeError:
-            pass
-    return True
+
+def setup_compilation_cache() -> Path:
+    """Turn on jax's persistent compilation cache at
+    :func:`compilation_cache_dir` and return that directory.  Every
+    executable is cached, however small or quick to compile.  Call it at
+    the start of an entry point's ``main()``, never at import.
+    """
+    path = str(compilation_cache_dir())
+    os.makedirs(path, exist_ok=True)
+    jax.config.update("jax_compilation_cache_dir", path)
+    jax.config.update("jax_persistent_cache_min_compile_time_secs", 0)
+    jax.config.update("jax_persistent_cache_min_entry_size_bytes", -1)
+    return Path(path)

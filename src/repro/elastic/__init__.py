@@ -9,7 +9,7 @@ with a few accelerators"; this package is that claim as a subsystem:
     count and the population size; :func:`plan_mesh` is the (data, model)
     grid planner for a single large member.
   * :mod:`repro.elastic.islands`  — the ``"islands"`` update backend
-    (``repro.compat.shard_map`` over the ``"pop"`` mesh axis), registered
+    (``jax.shard_map`` over the ``"pop"`` mesh axis), registered
     in the ``repro.pop`` backend registry: a one-line config swap.
   * :mod:`repro.elastic.resize`   — elastic population shrink/grow (worst
     members dropped, PBT clones refill), applied uniformly to training

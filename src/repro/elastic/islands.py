@@ -4,7 +4,7 @@
 the jitted vmapped update; this backend makes the paper's §5.1 topology
 *explicit* instead: the population axis is split over the ``"pop"`` mesh
 axis of an :class:`~repro.elastic.layout.IslandLayout` with
-``repro.compat.shard_map``, so each island runs a plain vectorized update
+``jax.shard_map``, so each island runs a plain vectorized update
 over only its own member group and NO cross-island communication exists in
 the update step at all (members are independent; the only collectives in
 island training are the PBT gathers at evolve time).
@@ -25,7 +25,6 @@ from functools import partial
 import jax
 from jax.sharding import PartitionSpec as P
 
-from repro import compat
 from repro.pop.backend import register_backend
 
 
@@ -87,15 +86,15 @@ def _build_islands(agent, num_steps: int, donate: bool, mesh=None):
                 body = (partial(local, hypers=None) if hypers is None
                         else local)
             elif hypers is None:
-                body = compat.shard_map(
+                body = jax.shard_map(
                     lambda s, b: local(s, b, None), mesh=m,
                     in_specs=(state_spec, batch_spec),
-                    out_specs=(state_spec, state_spec))
+                    out_specs=(state_spec, state_spec), check_vma=False)
             else:
-                body = compat.shard_map(
+                body = jax.shard_map(
                     local, mesh=m,
                     in_specs=(state_spec, batch_spec, state_spec),
-                    out_specs=(state_spec, state_spec))
+                    out_specs=(state_spec, state_spec), check_vma=False)
             fn = compiled[key] = jax.jit(
                 body, donate_argnums=(0,) if donate else ())
         if hypers is None:

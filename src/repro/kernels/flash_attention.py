@@ -8,6 +8,8 @@ product.  GQA is expressed in the BlockSpec index map (kv head = h // group)
 — no KV replication in memory.
 
 Layout: q (B, H, S, D), k/v (B, Hkv, S, D) -> out (B, H, S, D).
+Float32 operands contract at full float32 (``Precision.HIGHEST``); Mosaic's
+default would contract them in one bfloat16 pass.
 """
 from __future__ import annotations
 
@@ -22,7 +24,7 @@ NEG_INF = -1.0e30
 
 
 def _kernel(q_ref, k_ref, v_ref, o_ref, m_scr, l_scr, acc, *, scale: float,
-            bq: int, bk: int, causal: bool):
+            bq: int, bk: int, causal: bool, precision):
     qi, ki = pl.program_id(2), pl.program_id(3)
 
     @pl.when(ki == 0)
@@ -39,7 +41,8 @@ def _kernel(q_ref, k_ref, v_ref, o_ref, m_scr, l_scr, acc, *, scale: float,
         q = q_ref[0, 0]                                   # (bq, D)
         k = k_ref[0, 0]                                   # (bk, D)
         v = v_ref[0, 0]
-        s = jnp.dot(q, k.T, preferred_element_type=jnp.float32) * scale
+        s = jnp.dot(q, k.T, precision=precision,
+                    preferred_element_type=jnp.float32) * scale
         if causal:
             rows = qi * bq + jax.lax.broadcasted_iota(jnp.int32, (bq, bk), 0)
             cols = ki * bk + jax.lax.broadcasted_iota(jnp.int32, (bq, bk), 1)
@@ -50,7 +53,8 @@ def _kernel(q_ref, k_ref, v_ref, o_ref, m_scr, l_scr, acc, *, scale: float,
         alpha = jnp.exp(m_prev - m_new)
         l_new = alpha * l_prev + jnp.sum(p, axis=-1, keepdims=True)
         acc[...] = acc[...] * alpha + jnp.dot(
-            p.astype(v.dtype), v, preferred_element_type=jnp.float32)
+            p.astype(v.dtype), v, precision=precision,
+            preferred_element_type=jnp.float32)
         m_scr[...] = m_new
         l_scr[...] = l_new
 
@@ -69,7 +73,10 @@ def flash_attention(q, k, v, *, causal: bool = True, scale: float | None = None,
     bq, bk = min(bq, s), min(bk, s)
     assert s % bq == 0 and s % bk == 0
 
-    kern = functools.partial(_kernel, scale=scale, bq=bq, bk=bk, causal=causal)
+    precision = (jax.lax.Precision.HIGHEST
+                 if jnp.result_type(q, k, v) == jnp.float32 else None)
+    kern = functools.partial(_kernel, scale=scale, bq=bq, bk=bk, causal=causal,
+                             precision=precision)
     return pl.pallas_call(
         kern,
         grid=(b, h, s // bq, s // bk),
@@ -87,4 +94,5 @@ def flash_attention(q, k, v, *, causal: bool = True, scale: float | None = None,
                         pltpu.VMEM((bq, 1), jnp.float32),
                         pltpu.VMEM((bq, d), jnp.float32)],
         interpret=interpret,
+        name="flash_attention",
     )(q, k, v)

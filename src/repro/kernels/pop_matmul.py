@@ -9,7 +9,11 @@ opportunity; this kernel makes the tiling explicit (and fuses the bias +
 activation epilogue, which XLA sometimes leaves unfused for tiny matmuls).
 
 Layout: x (N, B, K), w (N, K, M), optional bias (N, M) -> y (N, B, M).
+The bias enters the kernel as (N, 1, M), so its block's last two dims are
+(1, bn) against (1, M) and meet the TPU's (8, 128) tiling rule.
 Grid: (N, B/bm, M/bn, K/bk), K innermost so the VMEM accumulator carries.
+Float32 operands contract at full float32 (``Precision.HIGHEST``); Mosaic's
+default would contract them in one bfloat16 pass.
 """
 from __future__ import annotations
 
@@ -21,12 +25,13 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 
-def _kernel(x_ref, w_ref, b_ref, o_ref, acc, *, activation: str):
+def _kernel(x_ref, w_ref, b_ref, o_ref, acc, *, activation: str,
+            precision):
     @pl.when(pl.program_id(3) == 0)
     def _():
         acc[...] = jnp.zeros_like(acc)
 
-    acc[...] += jnp.dot(x_ref[0], w_ref[0],
+    acc[...] += jnp.dot(x_ref[0], w_ref[0], precision=precision,
                         preferred_element_type=jnp.float32)
 
     @pl.when(pl.program_id(3) == pl.num_programs(3) - 1)
@@ -70,15 +75,19 @@ def pop_matmul(x, w, b=None, *, activation: str = "none",
         pl.BlockSpec((1, bk, bn), lambda i, j, l, kk: (i, kk, l)),
     ]
     args = [x, w]
+    precision = (jax.lax.Precision.HIGHEST
+                 if jnp.result_type(x, w) == jnp.float32 else None)
     if b is not None:
-        in_specs.append(pl.BlockSpec((1, bn), lambda i, j, l, kk: (i, l)))
-        args.append(b)
-        kern = functools.partial(_kernel, activation=activation)
+        in_specs.append(pl.BlockSpec((1, 1, bn),
+                                     lambda i, j, l, kk: (i, 0, l)))
+        args.append(b.reshape(n, 1, m))
+        kern = functools.partial(_kernel, activation=activation,
+                                 precision=precision)
     else:
         kern = functools.partial(
-            lambda xr, wr, orf, acc, activation: _kernel(
-                xr, wr, None, orf, acc, activation=activation),
-            activation=activation)
+            lambda xr, wr, orf, acc, **kw: _kernel(xr, wr, None, orf, acc,
+                                                   **kw),
+            activation=activation, precision=precision)
 
     return pl.pallas_call(
         kern,
@@ -88,4 +97,5 @@ def pop_matmul(x, w, b=None, *, activation: str = "none",
         out_shape=jax.ShapeDtypeStruct((n, bsz, m), x.dtype),
         scratch_shapes=[pltpu.VMEM((bm, bn), jnp.float32)],
         interpret=interpret,
+        name="pop_matmul",
     )(*args)

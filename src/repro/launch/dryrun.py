@@ -30,7 +30,6 @@ from repro.launch.hlo_analysis import analyze_hlo, roofline_terms
 from repro.launch.mesh import make_production_mesh
 from repro.models import lm as lm_mod
 from repro.models.sharding import batch_spec, param_specs
-from repro import compat
 
 
 def _named(mesh, spec_tree):
@@ -119,7 +118,7 @@ def build_cell(arch: str, shape_name: str, *, multi_pod: bool = False,
     batch = lm_mod.input_specs(cfg, shape)
     batch_sh = _batch_shardings(mesh, batch)
 
-    with compat.set_mesh(mesh):
+    with jax.sharding.set_mesh(mesh):
         if shape.kind == "train":
             tcfg = TrainConfig()
             opt_init, train_step = lm_mod.make_train_step(cfg, tcfg)
@@ -248,7 +247,7 @@ def build_population_cell(arch: str, shape_name: str, n: int, *,
             lambda p, o, bi, sc: train_step(p, o, bi, step, lr_scale=sc)
         )(params, opt, b, hypers["lr_scale"])
 
-    with compat.set_mesh(mesh), population_mode():
+    with jax.sharding.set_mesh(mesh), population_mode():
         out_struct = jax.eval_shape(pop_step, pop_struct, opt_struct, batch,
                                     step_struct, hyper_struct)
         fn = jax.jit(pop_step,

@@ -17,9 +17,9 @@
 ``python -m repro.launch.serve --arch qwen2-0.5b --smoke --tokens 32``
 ``python -m repro.launch.serve --algo td3 --ckpt-dir /tmp/repro_ckpt``
 
-``--compile-cache DIR`` points jax's persistent compilation cache at DIR
-(shared with ``launch/train.py``) so serving restarts skip cold XLA
-compiles — see ``benchmarks/compile_time.py`` for the measured win.
+Both launchers share jax's persistent compilation cache
+(``repro.compat.setup_compilation_cache``: ``$JAX_COMPILATION_CACHE_DIR``,
+else ``<repo>/.jax_cache``), so serving restarts skip cold XLA compiles.
 """
 from __future__ import annotations
 
@@ -30,6 +30,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro import compat
 from repro.configs import get_config
 from repro.models import lm as lm_mod
 from repro.telemetry import make_telemetry
@@ -69,7 +70,8 @@ def _serve_rl(args):
 
     Requests are synthesized from env resets (the env is the traffic
     model this box has); a real frontend swaps :func:`_request_batch` for
-    its socket and keeps everything else.
+    its socket and keeps everything else.  Returns the served actions,
+    ``(requests, batch, act_dim)``.
     """
     from repro.checkpoint import CheckpointManager
     from repro.envs import make
@@ -125,7 +127,7 @@ def _serve_rl(args):
     server.warmup()
     server.serve(_request_batch(key))
 
-    lat = []
+    lat, served_actions = [], []
     t0 = time.time()
     for i in range(args.requests):
         telemetry.tick_profile(i, args.profile, iters=args.profile_iters)
@@ -134,6 +136,7 @@ def _serve_rl(args):
         t1 = time.perf_counter()
         actions = server.serve(obs)
         lat.append(time.perf_counter() - t1)
+        served_actions.append(actions)
         if args.poll_every and (i + 1) % args.poll_every == 0:
             # a promotion of a new ensemble SIZE recompiles the serving
             # executable once — attribute those compile rows to it
@@ -156,7 +159,7 @@ def _serve_rl(args):
                      compiles=telemetry.compile_count,
                      compile_secs=round(telemetry.compile_secs, 4))
     telemetry.close()
-    return served / dt
+    return np.stack([np.asarray(a) for a in served_actions])
 
 
 def main(argv=None):
@@ -196,9 +199,6 @@ def main(argv=None):
     ap.add_argument("--prompt-len", type=int, default=8)
     ap.add_argument("--tokens", type=int, default=16)
     ap.add_argument("--seed", type=int, default=0)
-    ap.add_argument("--compile-cache", default=None, metavar="DIR",
-                    help="persistent jax compilation cache directory "
-                    "(share it with launch/train.py)")
     ap.add_argument("--log-dir", default=None, metavar="DIR",
                     help="write structured telemetry (latency histogram, "
                     "promotion audit trail, compile events) to "
@@ -215,9 +215,7 @@ def main(argv=None):
 
     if (args.arch is None) == (args.algo is None):
         ap.error("pass exactly one of --arch (LM) or --algo (RL ensemble)")
-    if args.compile_cache:
-        from repro import compat
-        compat.enable_compilation_cache(args.compile_cache)
+    compat.setup_compilation_cache()
     if args.algo is not None:
         return _serve_rl(args)
 

@@ -32,7 +32,8 @@ Production features exercised here (scaled down to whatever devices exist):
     dropped (or PBT clones refill) via ``repro.elastic.restore_elastic``,
     so losing accelerators between runs never strands a checkpoint
   * synthetic sharded token pipeline with restart-stable streams
-  * persistent XLA compilation cache (``--compile-cache DIR``, shared with
+  * persistent XLA compilation cache (``repro.compat.setup_compilation_cache``:
+    ``$JAX_COMPILATION_CACHE_DIR``, else ``<repo>/.jax_cache``, shared with
     ``launch/serve.py``) so restarts don't pay cold compiles.
 """
 from __future__ import annotations
@@ -44,6 +45,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro import compat
 from repro.configs import TrainConfig, get_config
 from repro.configs.base import HyperSpace, PopulationConfig
 from repro.data import host_batches
@@ -112,8 +114,10 @@ def _run_rl(args):
     def on_iter(it, metrics, stats, fitness, lineage):
         telemetry.tick_profile(it, args.profile, iters=args.profile_iters)
         if fitness is not None:
-            best["fitness"] = max(best["fitness"], float(np.max(fitness)))
-        if (it + 1) % args.ckpt_every == 0 or it == args.steps - 1:
+            best["fitness"] = max(best["fitness"],
+                                  float(np.max(np.asarray(fitness))))
+        if args.ckpt_every and ((it + 1) % args.ckpt_every == 0
+                                or it == args.steps - 1):
             trainer.save()
 
     trainer.run_env_loop(args.steps, eval_every=args.eval_every,
@@ -186,7 +190,9 @@ def main(argv=None):
     ap.add_argument("--smoke", action="store_true",
                     help="use the reduced same-family config (CPU-sized)")
     ap.add_argument("--ckpt-dir", default="/tmp/repro_ckpt")
-    ap.add_argument("--ckpt-every", type=int, default=50)
+    ap.add_argument("--ckpt-every", type=int, default=50,
+                    help="checkpoint every N steps and at the last one "
+                    "(0 = never)")
     ap.add_argument("--resume", default="auto", choices=["auto", "none"])
     ap.add_argument("--devices", type=int, default=0,
                     help="devices to lay the islands over (0 = all); the "
@@ -203,11 +209,6 @@ def main(argv=None):
                     "(worst members dropped / PBT clones refill)")
     ap.add_argument("--lr", type=float, default=3e-4)
     ap.add_argument("--seed", type=int, default=0)
-    ap.add_argument("--compile-cache", default=None, metavar="DIR",
-                    help="persistent jax compilation cache directory: "
-                    "restarts (and launch/serve.py, pointed at the same "
-                    "DIR) reuse compiled executables instead of paying "
-                    "cold XLA compiles")
     ap.add_argument("--log-dir", default=None, metavar="DIR",
                     help="write structured run telemetry (phase timers, "
                     "per-member fitness/hypers, lineage events, compile "
@@ -222,9 +223,7 @@ def main(argv=None):
 
     if (args.arch is None) == (args.algo is None):
         ap.error("pass exactly one of --arch (LM) or --algo (RL)")
-    if args.compile_cache:
-        from repro import compat
-        compat.enable_compilation_cache(args.compile_cache)
+    compat.setup_compilation_cache()
     if args.algo is not None:
         return _run_rl(args)
 
@@ -306,7 +305,8 @@ def main(argv=None):
         # iteration/evolve rows flow through the telemetry console sink;
         # only the checkpoint cadence (which wants a materialized loss for
         # the extras) stays host-side here
-        if (step + 1) % args.ckpt_every == 0 or step == args.steps - 1:
+        if args.ckpt_every and ((step + 1) % args.ckpt_every == 0
+                                or step == args.steps - 1):
             last["loss"] = float(jnp.mean(metrics["loss"]))
             trainer.save({"loss": last["loss"]})
 

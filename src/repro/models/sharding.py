@@ -19,7 +19,6 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
-from repro import compat
 
 # parameter-name → (spec for trailing dims) tables.  Leading stacked layer
 # axes are padded with None automatically.  "F" = fsdp axes, "M" = model.
@@ -138,8 +137,8 @@ def param_specs(params, mesh):
 def constrain(x, *spec):
     """with_sharding_constraint that no-ops outside a mesh context and drops
     non-dividing axes. ``spec`` entries may be 'F'/'M' symbols."""
-    mesh = compat.get_abstract_mesh()
-    if mesh is None or mesh.empty:
+    mesh = jax.sharding.get_abstract_mesh()
+    if mesh.empty:
         return x
     dims = []
     for d, sym in zip(x.shape, spec):
@@ -161,8 +160,8 @@ def constrain_tree(params):
     per-layer full-tensor gradient all-reduces into reduce-scatters (§Perf
     iteration 1 — a 2-4x collective-bytes reduction on MoE/dense train).
     """
-    mesh = compat.get_abstract_mesh()
-    if mesh is None or mesh.empty:
+    mesh = jax.sharding.get_abstract_mesh()
+    if mesh.empty:
         return params
 
     def one(path, leaf):

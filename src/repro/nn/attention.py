@@ -15,7 +15,6 @@ from typing import Any
 
 import jax
 import jax.numpy as jnp
-from repro import compat
 
 from repro.models.sharding import constrain
 from repro.nn.basic import lecun_normal, rmsnorm_init, rmsnorm_apply
@@ -25,8 +24,8 @@ BIG_NEG = -2.0e38  # mask value in fp32 softmax
 
 
 def _heads_divide_model(num_heads: int) -> bool:
-    mesh = compat.get_abstract_mesh()
-    if mesh is None or mesh.empty or "model" not in mesh.axis_names:
+    mesh = jax.sharding.get_abstract_mesh()
+    if mesh.empty or "model" not in mesh.axis_names:
         return False
     return num_heads % mesh.shape["model"] == 0
 

@@ -19,7 +19,6 @@ from functools import partial
 import jax
 import jax.numpy as jnp
 
-from repro import compat
 from jax.sharding import PartitionSpec as P
 
 from repro.optim.compress import compress_tree, decompress_tree
@@ -66,7 +65,8 @@ def make_dp_update(grad_fn, opt_update, mesh, *, axis: str = "data",
 
     spec_rep = P()
     spec_data = P(axis)
-    return jax.jit(compat.shard_map(
+    return jax.jit(jax.shard_map(
         local_update, mesh=mesh,
         in_specs=(spec_rep, spec_rep, spec_rep, spec_data),
-        out_specs=(spec_rep, spec_rep, spec_rep, spec_rep)))
+        out_specs=(spec_rep, spec_rep, spec_rep, spec_rep),
+        check_vma=False))

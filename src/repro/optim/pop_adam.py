@@ -148,23 +148,14 @@ def population_adam(lr: float = 3e-4, b1: float = 0.9, b2: float = 0.999,
         nf, _ = _flatten(state.nu)
 
         from repro.kernels.pop_adam import pop_adam as _pa
-        p = pf.shape[1]
-        blk = min(block, p)
-        pad = (-p) % blk
-        if pad:
-            z = jnp.zeros((n, pad), jnp.float32)
-            pf, gf, mf, nf = (jnp.concatenate([x, z], axis=1)
-                              for x in (pf, gf, mf, nf))
         p2, m2, v2 = _pa(pf, gf, mf, nf, lr_vec, step, b1=b1, b2=b2,
-                         eps=eps, block=blk,
+                         eps=eps, block=block,
                          interpret=jax.default_backend() != "tpu")
-        if pad:
-            p2, m2, v2 = (x[:, :p] for x in (p2, m2, v2))
         if decoupled:
             # the kernel has no decay term; post-apply it (kernel mode
             # is numerics-checked against the fallback, not bitwise)
             wd_vec = jnp.broadcast_to(jnp.asarray(wd, jnp.float32), (n,))
-            p2 = p2 - (lr_vec * wd_vec)[:, None] * pf[:, :p2.shape[1]]
+            p2 = p2 - (lr_vec * wd_vec)[:, None] * pf
 
         new_state = AdamState(step=step, mu=rebuild(m2, state.mu),
                               nu=rebuild(v2, state.nu))

@@ -43,6 +43,16 @@ from repro.pop.strategy import make_strategy
 from repro.telemetry import RunTelemetry
 
 
+@jax.jit
+def _unstack(tree):
+    """Per-row slices of a stacked tree in one dispatch: one compile per
+    tree structure, made in the first fused epoch, where N eager ``x[i]``
+    would compile once per index — after warmup, as steady-state compiles —
+    upload each index and dispatch once per leaf and row."""
+    n = jax.tree.leaves(tree)[0].shape[0]
+    return [jax.tree.map(lambda x: x[i], tree) for i in range(n)]
+
+
 class PopTrainer:
     def __init__(self, agent, pcfg: PopulationConfig | None = None, *,
                  seed: int = 0, key=None, strategy=None, mesh=None,
@@ -202,6 +212,11 @@ class PopTrainer:
                                           hypers=self.hypers,
                                           policy_lag=policy_lag,
                                           **engine_kwargs)
+        if self.layout is not None:
+            # the engine builds its buffers and env states on the default
+            # device; spread their member axis over the islands up front
+            r = self._rollout
+            r.bufs, r.vstate = self.layout.place((r.bufs, r.vstate))
         return self._rollout
 
     @property
@@ -359,18 +374,10 @@ class PopTrainer:
                                      self.hypers,
                                      self.strategy.export_state(), self.key)
             self.step_count += epoch_len
-            # per-iteration bookkeeping slices the stacked outputs with
-            # python index constants — host-to-device uploads of an int32
-            # each, never a device sync.  Scope-allow them so the whole
-            # loop still runs under transfer_guard("disallow") (the
-            # device-to-host direction stays guarded: nothing here fetches)
-            with jax.transfer_guard_host_to_device("allow"):
-                self._fused_epoch_bookkeeping(
-                    base, start, epoch_len, eval_every, n_evals, evolving,
-                    hypers_before, new_hypers, strat_state, m_stack,
-                    s_stack, dids, evals, fitness, lineage, on_iter)
-                metrics = jax.tree.map(lambda x: x[-1], m_stack)
-                stats = jax.tree.map(lambda x: x[-1], s_stack)
+            metrics, stats = self._fused_epoch_bookkeeping(
+                base, start, epoch_len, eval_every, n_evals, evolving,
+                hypers_before, new_hypers, strat_state, m_stack, s_stack,
+                dids, evals, fitness, lineage, on_iter)
         return metrics, stats
 
     def _fused_epoch_bookkeeping(self, base, start, epoch_len, eval_every,
@@ -379,19 +386,16 @@ class PopTrainer:
                                  dids, evals, fitness, lineage, on_iter):
         """Re-emit the eager loop's per-iteration side effects (telemetry
         rows, fitness-window appends, the evolve bookkeeping, ``on_iter``)
-        from one fused epoch's stacked device outputs."""
-        # per-iteration metric slices exist only for the telemetry rows /
-        # the on_iter hook; with neither attached, skip the dispatch of
-        # epoch_len x len(metrics) slice ops entirely
-        emit = self.telemetry.enabled or on_iter is not None
+        from one fused epoch's stacked device outputs; returns the last
+        iteration's ``(metrics, stats)``.  Slicing stays on device, so the
+        loop runs under ``transfer_guard("disallow")``."""
+        rows = _unstack((m_stack, s_stack, dids))
+        fits = _unstack(evals) if n_evals else None
         for i in range(epoch_len):
-            metrics = stats = None
-            if emit:
-                metrics = jax.tree.map(lambda x: x[i], m_stack)
-                stats = jax.tree.map(lambda x: x[i], s_stack)
+            metrics, stats, did_i = rows[i]
             fit_i = None
             if n_evals and (i + 1) % eval_every == 0:
-                fit_i = evals[(i + 1) // eval_every - 1]
+                fit_i = fits[(i + 1) // eval_every - 1]
                 if not evolving:
                     self.report_fitness(fit_i)
                 self.telemetry.record_members(base + i + 1, fitness=fit_i,
@@ -412,12 +416,11 @@ class PopTrainer:
                 if self.telemetry.enabled:
                     self.telemetry.record_members(base + epoch_len,
                                                   hypers=self.hypers)
-            if emit:
-                self.telemetry.record_iteration(base + i, metrics=metrics,
-                                                stats=stats,
-                                                did_update=dids[i])
+            self.telemetry.record_iteration(base + i, metrics=metrics,
+                                            stats=stats, did_update=did_i)
             if on_iter is not None:
                 on_iter(base + i - start, metrics, stats, fit_i, lin_i)
+        return metrics, stats
 
     # ---------------------------------------------------------------- evolve
     def report_fitness(self, fitness):
@@ -449,13 +452,13 @@ class PopTrainer:
         return None
 
     def evolve(self):
-        self.last_fitness = self.fitness()
-        self.key, k = jax.random.split(self.key)
         with self.telemetry.phase("evolve"), \
                 self.telemetry.compile_scope("evolve"):
-            # the strategy's executable compiles on the FIRST evolve (after
-            # warmup flipped to "steady"); label it so steady-state compile
-            # counts stay an honest recompile alarm
+            # the strategy's executable (and the window mean's) compiles on
+            # the FIRST evolve (after warmup flipped to "steady"); label it
+            # so steady-state compile counts stay an honest recompile alarm
+            self.last_fitness = self.fitness()
+            self.key, k = jax.random.split(self.key)
             self.state, self.hypers, lineage = self.strategy.evolve(
                 k, self.state, self.hypers, jnp.asarray(self.last_fitness))
         # pre-evolve fitness describes states that may just have been

@@ -49,6 +49,34 @@ from repro.rollout.collector import Collector, default_exploration
 from repro.rollout.evaluator import Evaluator
 from repro.rollout.vecenv import VecEnv, episode_stats, reset_stats
 
+# what a jax ``Compiled`` raises when called with arguments whose dtypes or
+# shapes, shardings or pytree differ from those it was lowered for
+_AOT_MISMATCH = (
+    "Argument types differ from the types for which this computation was "
+    "compiled",
+    "Computation was compiled for input shardings that disagree",
+    "Function compiled with input pytree does not match")
+
+
+def aot_mismatch(err: Exception) -> bool:
+    """Whether ``err`` is an AOT executable refusing its arguments — the
+    one error the engines answer by falling back to their jit path.  Any
+    other error (an OOM, a runtime fault) propagates."""
+    return str(err).startswith(_AOT_MISMATCH)
+
+
+def abstract_args(tree):
+    """``ShapeDtypeStruct`` per leaf, carrying the sharding of each leaf
+    committed to devices, so an AOT lowering accepts the very arrays it was
+    built from (on a multi-device mesh too); uncommitted leaves stay free
+    to follow the others, as they do under jit."""
+    def one(x):
+        committed = isinstance(x, jax.Array) and x.committed
+        return jax.ShapeDtypeStruct(
+            jnp.shape(x), jnp.result_type(x),
+            sharding=x.sharding if committed else None)
+    return jax.tree.map(one, tree)
+
 
 class RolloutEngine:
     """Owns VecEnv states + the population experience buffers + the fused
@@ -372,8 +400,9 @@ class RolloutEngine:
         try:
             out = self._iteration_exec(state, self.bufs, self.vstate,
                                        hypers, key)
-        except Exception:
-            if self._iteration_exec is self._iteration:
+        except (TypeError, ValueError) as e:
+            if self._iteration_exec is self._iteration or \
+                    not aot_mismatch(e):
                 raise
             # an AOT executable only accepts the exact shapes it was
             # lowered for — fall back to the jit wrapper permanently
@@ -400,11 +429,10 @@ class RolloutEngine:
         """
         import threading
 
-        abstract = lambda t: jax.tree.map(
-            lambda x: jax.ShapeDtypeStruct(jnp.shape(x),
-                                           jnp.result_type(x)), t)
-        args = (abstract(state), abstract(self.bufs), abstract(self.vstate),
-                None if hypers is None else abstract(hypers), abstract(key))
+        args = (abstract_args(state), abstract_args(self.bufs),
+                abstract_args(self.vstate),
+                None if hypers is None else abstract_args(hypers),
+                abstract_args(key))
         box = {}
 
         def work():

@@ -57,7 +57,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.data.replay_buffer import buffer_sample
-from repro.rollout.engine import RolloutEngine
+from repro.rollout.engine import RolloutEngine, abstract_args, aot_mismatch
 from repro.rollout.vecenv import episode_stats
 
 
@@ -136,8 +136,8 @@ class OverlapEngine(RolloutEngine):
         fn = self._exec[which]
         try:
             return fn(*args)
-        except Exception:
-            if fn is self._progs[which]:
+        except (TypeError, ValueError) as e:
+            if fn is self._progs[which] or not aot_mismatch(e):
                 raise
             # AOT executables only accept the shapes they were lowered for
             self._exec[which] = self._progs[which]
@@ -198,13 +198,11 @@ class OverlapEngine(RolloutEngine):
         docstring for the contract)."""
         import threading
 
-        abstract = lambda t: jax.tree.map(
-            lambda x: jax.ShapeDtypeStruct(jnp.shape(x),
-                                           jnp.result_type(x)), t)
-        a_state, a_bufs, a_vstate = (abstract(state), abstract(self.bufs),
-                                     abstract(self.vstate))
-        a_h = None if hypers is None else abstract(hypers)
-        a_key = abstract(key)
+        a_state, a_bufs, a_vstate = (abstract_args(state),
+                                     abstract_args(self.bufs),
+                                     abstract_args(self.vstate))
+        a_h = None if hypers is None else abstract_args(hypers)
+        a_key = abstract_args(key)
         box = {}
 
         def work():

@@ -34,7 +34,6 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro import compat
 from repro.serve.ensemble import ServingSet
 from repro.serve.forward import PolicyForward
 from repro.telemetry import LatencyWindow
@@ -88,9 +87,10 @@ class BatchServer:
         self._request_sharding = None
         if mesh is not None:
             from jax.sharding import NamedSharding, PartitionSpec as P
-            members_fn = compat.shard_map(
+            members_fn = jax.shard_map(
                 forward.members, mesh=mesh,
-                in_specs=(P("pop"), P()), out_specs=P("pop"))
+                in_specs=(P("pop"), P()), out_specs=P("pop"),
+                check_vma=False)
             # requests enter replicated over the mesh; placing them there
             # explicitly keeps the hot path free of implicit reshards
             self._request_sharding = NamedSharding(mesh, P())

@@ -21,7 +21,6 @@ from repro.core.distributed import (population_sharding, shard_population,
 from repro.core import population_init, pbt_step, sample_hypers, vectorized_update
 from repro.configs.base import HyperSpace, PopulationConfig
 from repro.rl import td3
-from repro import compat
 
 mesh = make_host_mesh(model=1, data=8)
 N = 8
@@ -42,7 +41,7 @@ batch = {
  "next_obs": jax.random.normal(key, (N, 16, 3)),
  "done": jnp.zeros((N, 16)),
 }
-with compat.set_mesh(mesh):
+with jax.sharding.set_mesh(mesh):
     update = vectorized_update(td3.update, donate=False)
     pop2, metrics = update(pop, batch, hypers)
     # PBT across the sharded population: the member gathers lower to

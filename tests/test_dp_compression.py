@@ -36,7 +36,7 @@ def test_dp_update_converges(compression):
     update = make_dp_update(grad_fn, opt_update, mesh,
                             compression=compression)
     key = jax.random.PRNGKey(0)
-    with compat.set_mesh(mesh):
+    with jax.sharding.set_mesh(mesh):
         for i in range(300):
             batch = jax.random.normal(jax.random.fold_in(key, i),
                                       (8 * len(jax.devices()), 8))
@@ -58,7 +58,7 @@ def test_compressed_matches_plain_within_tolerance():
         update = make_dp_update(grad_fn, opt_update, mesh,
                                 compression=compression)
         key = jax.random.PRNGKey(1)
-        with compat.set_mesh(mesh):
+        with jax.sharding.set_mesh(mesh):
             for i in range(100):
                 batch = jax.random.normal(jax.random.fold_in(key, i),
                                           (8 * len(jax.devices()), 8))

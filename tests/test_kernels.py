@@ -22,7 +22,8 @@ import numpy as np
 import pytest
 
 from repro.kernels import ops, ref
-from repro.kernels.pop_adam import pop_adam
+from repro.kernels.pop_adam import VMEM_BUDGET, pop_adam
+from repro.kernels.pop_adam import tiles as pop_adam_tiles
 from repro.kernels.pop_matmul import supports_shapes
 from repro.nn.basic import mlp_init, mlp_apply
 from repro.rl import networks as nets
@@ -137,10 +138,12 @@ def _adam_parity(seed, n, psize, block, lr, step):
 
 
 def _adam_cases():
-    # block clamps to min(block, P) and then P must tile: cover P inside
-    # one block (odd P included) and P an exact multiple of the block
+    # cover P inside one block (odd P included), P an exact multiple of the
+    # block, P the kernel pads to a block multiple, and several 8-member
+    # row blocks
     cases = [(1, 1, 32), (1, 128, 32), (2, 64, 64), (3, 257, 512),
-             (4, 8192, 4096)]
+             (4, 8192, 4096), (3, 300, 128), (16, 300, 128),
+             (24, 1000, 256)]
     for _ in range(5):
         n = int(_RNG.integers(1, 7))
         block = int(2 ** _RNG.integers(5, 12))
@@ -158,6 +161,17 @@ def test_pop_adam_sweep(n, psize, block, step):
     lr = jnp.linspace(1e-4, 3e-3, n)
     _adam_parity(n * psize + step, n, psize, block,
                  lr, jnp.asarray(step, jnp.int32))
+
+
+@pytest.mark.parametrize("n", [1, 7, 8, 36, 80, 81, 1001, 4096])
+def test_pop_adam_tiles_fit_vmem(n):
+    """A tile's VMEM does not grow with the population: rows are 8
+    members where 8 divides N, and the lane block shrinks otherwise."""
+    rows, lanes = pop_adam_tiles(n, 10 ** 6)
+    assert n % rows == 0 and lanes % 128 == 0
+    assert rows == (8 if n % 8 == 0 else n)
+    assert 7 * 2 * 4 * -(-rows // 8) * 8 * lanes <= VMEM_BUDGET
+    assert pop_adam_tiles(n, 100) == (rows, 100)      # P inside one tile
 
 
 def test_pop_adam_per_member_step():
@@ -342,6 +356,31 @@ def test_ssd_sweep(b, h, s, p, n, chunk, dtype):
                                **TOL[dtype])
     np.testing.assert_allclose(np.asarray(sf), np.asarray(sr, np.float32),
                                **TOL[dtype])
+
+
+def test_ssd_long_chunk_against_float64():
+    """At zamba2's chunk of 256 the in-chunk log-decay cumsums reach
+    hundreds; the kernel's segsum must still be as exact as float32 allows,
+    here against the scan run in float64."""
+    b, h, s, p, n, chunk = 1, 2, 512, 64, 64, 256
+    ks = jax.random.split(KEY, 6)
+    x = jax.random.normal(ks[0], (b, h, s, p))
+    dt = jax.nn.softplus(jax.random.normal(ks[1], (b, h, s)))
+    a = -jnp.exp(jax.random.normal(ks[2], (h,)) * 0.3)
+    bb = jax.random.normal(ks[3], (b, s, n))
+    cc = jax.random.normal(ks[4], (b, s, n))
+    h0 = jax.random.normal(ks[5], (b, h, p, n)) * 0.1
+    y, sf = ops.ssd(x, dt, a, bb, cc, h0, chunk=chunk, interpret=True)
+    with jax.enable_x64(True):
+        f64 = [np.asarray(t, np.float64) for t in (x, dt, a, bb, cc, h0)]
+        yr, sr = ref.ssd_scan(np.moveaxis(f64[0], 1, 2),
+                              np.moveaxis(f64[1], 1, 2), *f64[2:])
+        yr, sr = np.moveaxis(np.asarray(yr), 1, 2), np.asarray(sr)
+    assert yr.dtype == np.float64
+    # absolute: |y| reaches ~100, where a relative 2e-4 would hide the
+    # ~1e-3 a plainly rounded cumsum costs
+    np.testing.assert_allclose(np.asarray(y), yr, atol=2e-4, rtol=0)
+    np.testing.assert_allclose(np.asarray(sf), sr, atol=2e-4, rtol=0)
 
 
 def test_grad_accum_equivalence():

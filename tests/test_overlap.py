@@ -15,11 +15,15 @@ Pins the acceptance properties of the split collect/update pipeline:
   * ZERO steady-state recompiles at lag 1 (both programs re-enter their
     caches) and no implicit host transfers post-warmup;
   * ``restore_elastic`` installs the background-AOT executables (the
-    resize-time recompile overlaps data movement);
+    resize-time recompile overlaps data movement); an AOT executable hands
+    over to the jit path only when it refuses its arguments, and keeps
+    running on a sharded islands mesh;
   * telemetry: ``block_every`` emits ``blocks`` dispatch/wait split rows
     that ``tools/report.py`` summarizes and ``--check`` accepts.
 """
 import json
+import os
+import subprocess
 import sys
 
 import jax
@@ -235,6 +239,82 @@ def test_restore_elastic_installs_aot_executables(tmp_path, policy_lag):
     dst.run_env_loop(2, eval_every=1)
     assert all(np.isfinite(np.asarray(x)).all()
                for x in jax.tree.leaves(dst.state))
+
+
+@pytest.mark.parametrize("policy_lag", [None, 1])
+def test_aot_fallback_only_on_argument_mismatch(policy_lag):
+    """An AOT executable that refuses its arguments hands over to the jit
+    path for good; any other error (an OOM, a runtime fault) propagates
+    instead of being retried on the jit path."""
+    tr = _build("td3", policy_lag=policy_lag)
+    tr.env_iteration()
+    eng = tr.rollout
+    refuses = jax.jit(lambda x: x).lower(
+        jax.ShapeDtypeStruct((1,), jnp.float32)).compile()
+
+    def faults(*args):
+        raise RuntimeError("RESOURCE_EXHAUSTED: out of memory")
+
+    def install(fn):
+        if policy_lag is None:
+            eng._iteration_exec = fn
+        else:
+            eng._exec = {"collect": fn, "update": fn}
+
+    install(faults)
+    with pytest.raises(RuntimeError, match="RESOURCE_EXHAUSTED"):
+        tr.env_iteration()
+    install(refuses)
+    tr.env_iteration()
+    if policy_lag is None:
+        assert eng._iteration_exec is eng._iteration
+    else:
+        assert eng._exec == eng._progs
+
+
+AOT_ISLANDS = """
+import json, sys
+import jax
+from repro.configs.base import PopulationConfig
+from repro.elastic import plan_layout
+from repro.envs import make
+from repro.pop import PopTrainer
+from repro.rl import get_algo, make_agent
+
+lag = None if sys.argv[1] == "none" else int(sys.argv[1])
+env = make("pendulum")
+pcfg = PopulationConfig(size=2, strategy="none", backend="islands",
+                        num_steps=2, donate=False,
+                        hyper_space=get_algo("td3").hyper_space)
+tr = PopTrainer(make_agent("td3", env.spec, hidden=(8, 8)), pcfg, seed=0,
+                layout=plan_layout(len(jax.devices()), 2))
+tr.attach_rollout(env, num_envs=2, collect_steps=8, batch_size=16,
+                  buffer_capacity=256, eval_envs=1, policy_lag=lag)
+tr.env_iteration()
+eng = tr.rollout
+err = eng.warm_compile_async(tr.state, tr.hypers, tr.key)()
+aot = eng._iteration_exec if lag is None else dict(eng._exec)
+for _ in range(2):
+    tr.env_iteration()
+kept = eng._iteration_exec is aot if lag is None else eng._exec == aot
+print(json.dumps({"error": None if err is None else repr(err),
+                  "kept": kept}))
+"""
+
+
+@pytest.mark.parametrize("policy_lag", ["none", "1"])
+def test_aot_executables_keep_running_on_islands_mesh(policy_lag):
+    """The AOT lowering takes each argument's sharding, so on a 2-device
+    islands mesh the executables accept the sharded state and are kept —
+    not refused on every call and silently replaced by a recompile."""
+    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    env = dict(os.environ, PYTHONPATH=os.path.join(repo, "src"),
+               XLA_FLAGS="--xla_force_host_platform_device_count=2")
+    r = subprocess.run([sys.executable, "-c", AOT_ISLANDS, policy_lag],
+                       env=env, capture_output=True, text=True, timeout=600)
+    assert r.returncode == 0, r.stderr[-3000:]
+    out = json.loads(r.stdout.strip().splitlines()[-1])
+    assert out == {"error": None, "kept": True}
 
 
 # ----------------------------------------------- dispatch/block telemetry

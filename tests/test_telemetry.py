@@ -198,9 +198,6 @@ def test_phase_timers_accumulate_and_clear(tmp_path):
 
 
 def test_compile_listener_counts_labels_and_unregisters(tmp_path):
-    from repro import compat
-    if compat.register_compile_listener(lambda e, s: None) is None:
-        pytest.skip("jax.monitoring not available")
     tel = RunTelemetry(JSONLSink(tmp_path / "t.jsonl", strict=True))
 
     jax.jit(lambda x: x * 2.0 + 1.0)(jnp.arange(3.0)).block_until_ready()
