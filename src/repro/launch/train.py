@@ -255,7 +255,6 @@ def main(argv=None):
     trainer = PopTrainer(LMAgent(cfg, tcfg), pcfg, seed=args.seed,
                          layout=layout, checkpoint_dir=args.ckpt_dir,
                          telemetry=telemetry)
-    trainer.tokens_per_step = args.batch * args.seq_len
 
     start_step = 0
     if args.resume == "auto":

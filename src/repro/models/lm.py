@@ -295,7 +295,11 @@ def _segment_forward(seg: Segment, seg_params, shared_p, cfg: LMConfig, h,
 
     if cfg.remat and train:
         body = jax.checkpoint(body)
-    h, (new_states, auxs) = jax.lax.scan(body, h, (seg_params, seg_state))
+    # the scope wraps the scan call, not only its body, so that the loop
+    # instruction itself carries it
+    with jax.named_scope("layers"):
+        h, (new_states, auxs) = jax.lax.scan(body, h,
+                                             (seg_params, seg_state))
     return h, new_states, jnp.sum(auxs)
 
 
@@ -312,7 +316,8 @@ def forward(params, cfg: LMConfig, batch, state=None, cache_index=None,
     if cfg.frontend == "audio_frames":
         h = batch["embeds"].astype(dtype)
     else:
-        h = cparams["embed"]["embedding"][tokens]
+        with jax.named_scope("embed"):
+            h = cparams["embed"]["embedding"][tokens]
         if cfg.frontend == "vision_patches" and "patch_embeds" in batch:
             npatch = batch["patch_embeds"].shape[1]
             if cache_index is None:  # full-sequence pass: splice patch prefix
@@ -346,10 +351,11 @@ def forward(params, cfg: LMConfig, batch, state=None, cache_index=None,
             new_state[seg.name] = seg_new
         aux_total = aux_total + aux
 
-    h = rmsnorm_apply(params["final_norm"], h)
-    if return_hidden:
-        return h, new_state, aux_total
-    logits = h @ _head_weight(cparams, cfg)
+    with jax.named_scope("head"):
+        h = rmsnorm_apply(params["final_norm"], h)
+        if return_hidden:
+            return h, new_state, aux_total
+        logits = h @ _head_weight(cparams, cfg)
     return logits, new_state, aux_total
 
 
@@ -389,21 +395,21 @@ def lm_loss(params, cfg: LMConfig, batch, train: bool = True):
     mask = jnp.ones(tokens.shape, jnp.float32).at[:, -1].set(0.0)
     if cfg.frontend == "vision_patches" and cfg.num_frontend_positions:
         mask = mask.at[:, :cfg.num_frontend_positions].set(0.0)
-    w = _head_weight(cast(params, jnp.dtype(cfg.dtype)), cfg)
-
-    if cfg.logits_chunk and hidden.shape[1] % cfg.logits_chunk == 0:
-        nc = hidden.shape[1] // cfg.logits_chunk
-        def body(carry, xs):
-            h_c, l_c, m_c = xs
-            ce, n = _token_ce(h_c @ w, l_c, m_c)
-            return (carry[0] + ce, carry[1] + n), None
-        reshape = lambda x: jnp.moveaxis(
-            x.reshape(x.shape[0], nc, cfg.logits_chunk, *x.shape[2:]), 1, 0)
-        (ce, n), _ = jax.lax.scan(
-            body, (jnp.zeros(()), jnp.zeros(())),
-            (reshape(hidden), reshape(labels), reshape(mask)))
-    else:
-        ce, n = _token_ce(hidden @ w, labels, mask)
+    with jax.named_scope("head"):
+        w = _head_weight(cast(params, jnp.dtype(cfg.dtype)), cfg)
+        if cfg.logits_chunk and hidden.shape[1] % cfg.logits_chunk == 0:
+            nc = hidden.shape[1] // cfg.logits_chunk
+            def body(carry, xs):
+                h_c, l_c, m_c = xs
+                ce, n = _token_ce(h_c @ w, l_c, m_c)
+                return (carry[0] + ce, carry[1] + n), None
+            reshape = lambda x: jnp.moveaxis(x.reshape(
+                x.shape[0], nc, cfg.logits_chunk, *x.shape[2:]), 1, 0)
+            (ce, n), _ = jax.lax.scan(
+                body, (jnp.zeros(()), jnp.zeros(())),
+                (reshape(hidden), reshape(labels), reshape(mask)))
+        else:
+            ce, n = _token_ce(hidden @ w, labels, mask)
     loss = ce / jnp.maximum(n, 1.0)
     if cfg.moe is not None:
         loss = loss + cfg.moe.aux_loss_weight * aux / max(
@@ -470,11 +476,12 @@ def make_train_step(cfg: LMConfig, tcfg: TrainConfig):
     def train_step(params, opt_state, batch, step, lr_scale=None,
                    weight_decay=None, warmup_frac=None):
         grads, loss, metrics = grads_of(params, batch)
-        lr = lr_at(step, lr_scale, warmup_frac)
-        updates, opt_state = opt_update(grads, opt_state, params,
-                                        lr_override=lr,
-                                        wd_override=weight_decay)
-        params = apply_updates(params, updates)
+        with jax.named_scope("optimizer"):
+            lr = lr_at(step, lr_scale, warmup_frac)
+            updates, opt_state = opt_update(grads, opt_state, params,
+                                            lr_override=lr,
+                                            wd_override=weight_decay)
+            params = apply_updates(params, updates)
         metrics = dict(metrics, loss=loss, step=step)
         return params, opt_state, metrics
 
@@ -505,10 +512,11 @@ def make_population_update(cfg: LMConfig, tcfg: TrainConfig, *, fused=None):
         from repro.pop.agent import LMState  # lazy: pop.agent imports lm
         h = hypers if hypers else {}
         grads, loss, metrics = jax.vmap(grads_of)(state.params, batch)
-        lr = lr_at(state.step, h.get("lr_scale"), h.get("warmup_frac"))
-        params, opt_state = pop_apply(state.params, grads, state.opt_state,
-                                      lr_override=lr,
-                                      wd_override=h.get("weight_decay"))
+        with jax.named_scope("optimizer"):
+            lr = lr_at(state.step, h.get("lr_scale"), h.get("warmup_frac"))
+            params, opt_state = pop_apply(
+                state.params, grads, state.opt_state, lr_override=lr,
+                wd_override=h.get("weight_decay"))
         metrics = dict(metrics, loss=loss, step=state.step)
         return LMState(params=params, opt_state=opt_state,
                        step=state.step + 1), metrics

@@ -116,13 +116,6 @@ class PopTrainer:
         self._window: deque = deque(maxlen=pcfg.fitness_window)
         self.last_fitness = None  # the (N,) fitness used at the last evolve
         self.step_count = 0
-        # LM workloads set tokens_per_step (per-member tokens consumed by
-        # one update call); step() then derives a dispatch-rate
-        # tokens_per_sec_per_member for the telemetry iter rows.  Host
-        # wall-clock between dispatches — no device sync in the hot path
-        # (benchmarks/lm_population.py does the blocked measurement).
-        self.tokens_per_step = None
-        self._iter_t = None
         self._rollout = None
         self._mgr = None
         if checkpoint_dir is not None:
@@ -140,25 +133,22 @@ class PopTrainer:
     def step(self, batch, fitness=None):
         """One update call (``pcfg.num_steps`` chained member-steps), plus —
         on cadence — one evolve.  Returns ``(metrics, lineage)`` where
-        lineage is None unless evolution ran this step."""
-        with self.telemetry.phase("update"):
-            self.state, metrics = self._update(self.state, batch,
-                                               self.hypers)
-        self.step_count += 1
-        fit = fitness if fitness is not None \
-            else self.agent.fitness_from_metrics(metrics)
-        if fit is not None:
-            self.report_fitness(fit)
-        lineage = self._maybe_evolve()
-        extra = {}
-        if self.tokens_per_step:
-            now = time.perf_counter()
-            if self._iter_t is not None and now > self._iter_t:
-                extra["tokens_per_sec_per_member"] = \
-                    self.tokens_per_step / (now - self._iter_t)
-            self._iter_t = now
-        self.telemetry.record_iteration(self.step_count - 1, metrics=metrics,
-                                        **extra)
+        lineage is None unless evolution ran this step.
+
+        The ``step`` phase holds the ``update`` phase (the executable call)
+        and the bookkeeping after it (fitness, the window, the evolve);
+        ``step`` minus ``update`` is that bookkeeping's host time."""
+        with self.telemetry.phase("step"):
+            with self.telemetry.phase("update"):
+                self.state, metrics = self._update(self.state, batch,
+                                                   self.hypers)
+            self.step_count += 1
+            fit = fitness if fitness is not None \
+                else self.agent.fitness_from_metrics(metrics)
+            if fit is not None:
+                self.report_fitness(fit)
+            lineage = self._maybe_evolve()
+        self.telemetry.record_iteration(self.step_count - 1, metrics=metrics)
         return metrics, lineage
 
     def run(self, steps: int, batch_fn, *, on_step=None):

@@ -231,8 +231,9 @@ class RolloutEngine:
         def iteration(state, bufs, vstate, hypers, key):
             kc, ks = jax.random.split(key)
             actors = self.agent.actor_params(state)
-            bufs, vstate = self._collect_insert(actors, bufs, vstate,
-                                                hypers, kc)
+            with jax.named_scope("collect"):
+                bufs, vstate = self._collect_insert(actors, bufs, vstate,
+                                                    hypers, kc)
             can = jnp.all(jax.vmap(
                 lambda b: self.exp.ready(b, B))(bufs))
 
@@ -249,7 +250,8 @@ class RolloutEngine:
             def skip(state):
                 return state, self._zero_metrics
 
-            state, metrics = jax.lax.cond(can, do_update, skip, state)
+            with jax.named_scope("update"):
+                state, metrics = jax.lax.cond(can, do_update, skip, state)
             return state, bufs, vstate, metrics, episode_stats(vstate), can
 
         return iteration
@@ -302,10 +304,12 @@ class RolloutEngine:
         def iteration(state, bufs, vstate, hypers, key):
             kc, kp = jax.random.split(key)
             actors = self.agent.actor_params(state)
-            bufs, vstate = self._collect_insert(actors, bufs, vstate,
-                                                hypers, kc)
-            batches = self.population_batches(bufs, actors, hypers, kp)
-            state, metrics = self._update_k(state, batches, hypers)
+            with jax.named_scope("collect"):
+                bufs, vstate = self._collect_insert(actors, bufs, vstate,
+                                                    hypers, kc)
+            with jax.named_scope("update"):
+                batches = self.population_batches(bufs, actors, hypers, kp)
+                state, metrics = self._update_k(state, batches, hypers)
             return (state, bufs, vstate, metrics, episode_stats(vstate),
                     jnp.ones((), bool))
 
@@ -343,6 +347,11 @@ class RolloutEngine:
         is the ``(num_evals, N)`` per-evaluation fitness record, and
         ``fitness`` / ``lineage`` describe the evolve (identity lineage
         when ``evolve_fn`` is None).
+
+        Its compiled instructions carry the ``jax.named_scope`` of their
+        part in their ``op_name``: ``collect`` (acting and the store),
+        ``update`` (sampling and the chained updates), ``eval`` and
+        ``evolve``, so that a profiler trace can be split by them.
         """
         iteration = self._iteration_fn
         evaluator = self.evaluator
@@ -368,9 +377,10 @@ class RolloutEngine:
                             agent.actor_params(state), k_ev)
                         return key, evals.at[
                             (i + 1) // eval_every - 1].set(fit)
-                    key, evals = jax.lax.cond(
-                        (i + 1) % eval_every == 0, do_eval,
-                        lambda args: args, (key, evals))
+                    with jax.named_scope("eval"):
+                        key, evals = jax.lax.cond(
+                            (i + 1) % eval_every == 0, do_eval,
+                            lambda args: args, (key, evals))
                 return ((state, bufs, vstate, key, evals),
                         (metrics, stats, did))
 
@@ -384,8 +394,9 @@ class RolloutEngine:
                        else jnp.zeros((n,)))
             if evolve_fn is not None:
                 key, k_evolve = jax.random.split(key)
-                state, hypers, lineage, strat_state = evolve_fn(
-                    k_evolve, state, hypers, fitness, strat_state)
+                with jax.named_scope("evolve"):
+                    state, hypers, lineage, strat_state = evolve_fn(
+                        k_evolve, state, hypers, fitness, strat_state)
             else:
                 lineage = jnp.arange(n)
             return (state, bufs, vstate, hypers, strat_state, key,

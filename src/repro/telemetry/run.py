@@ -9,11 +9,15 @@ hyper trajectories, per-phase timing and compile-event counts.
 
 Design constraint (the one that makes this engineering, not logging glue):
 **nothing here may touch array values on the caller's thread.**  Phase
-timers are host wall-clock (``perf_counter``) around *dispatch*; rows
-carry jax arrays by reference and the sink's writer thread fetches them
-after they have materialized.  The fused train iteration and the ensemble
-serve call stay ONE jitted donated call each — asserted by the
-transfer-guard tests running with a live JSONL sink attached.
+timers take host wall-clock (``perf_counter``) and the calling thread's
+CPU time (``thread_time``) around *dispatch*: wall minus CPU is the time
+the thread waited in the runtime rather than worked.  Each phase is also a
+``jax.profiler.TraceAnnotation`` named ``pop.<phase>``, so a profiler trace
+shows it on the device's clock.  Rows carry jax arrays by reference and
+the sink's writer thread fetches them after they have materialized.  The
+fused train iteration and the ensemble serve call stay ONE jitted donated
+call each — asserted by the transfer-guard tests running with a live
+JSONL sink attached.
 
 Compile tracking rides ``repro.compat.register_compile_listener`` (jax's
 monitoring events): every XLA backend compile becomes a ``compile`` row
@@ -25,6 +29,7 @@ tail PR 3/PR 5 measured).
 """
 from __future__ import annotations
 
+import gc
 import os
 import time
 from contextlib import contextmanager
@@ -76,6 +81,11 @@ class RunTelemetry:
         self.run_id = run_id or _run_id()
         self._t0 = time.perf_counter()
         self._phases: dict[str, float] = {}
+        self._phases_cpu: dict[str, float] = {}
+        # cumulative [count, wall s, cpu s] per phase (and "gc"); never
+        # cleared, so a reader takes differences over its own window
+        self._totals: dict[str, list] = {}
+        self._gc_open = None
         self._blocks: dict[str, float] = {}
         self._compile_label = "warmup"
         self.compile_count = 0
@@ -99,17 +109,69 @@ class RunTelemetry:
 
     @contextmanager
     def phase(self, name: str):
-        """Accumulate host wall-clock of the enclosed block into ``name``
-        for the current iteration row.  Times *dispatch*, deliberately: a
-        fused call's device time shows up as whichever later phase blocks
-        on its results (or in the profiler trace — this is a cheap
-        always-on timer, not a tracer)."""
-        t0 = time.perf_counter()
+        """Accumulate the enclosed block's host wall-clock and this
+        thread's CPU time into ``name`` for the current iteration row and
+        into :meth:`totals`, under a profiler annotation ``pop.<name>``.
+        Times *dispatch*, deliberately: a fused call's device time shows up
+        as whichever later phase blocks on its results (or in the profiler
+        trace — this is a cheap always-on timer, not a tracer).  Wall
+        minus CPU is what the thread spent waiting, e.g. in the runtime
+        for buffers a running step still holds."""
+        t0, c0 = time.perf_counter(), time.thread_time()
+        try:
+            with jax.profiler.TraceAnnotation(f"pop.{name}"):
+                yield
+        finally:
+            dt = time.perf_counter() - t0
+            dc = time.thread_time() - c0
+            self._phases[name] = self._phases.get(name, 0.0) + dt
+            self._phases_cpu[name] = self._phases_cpu.get(name, 0.0) + dc
+            self._count(name, dt, dc)
+
+    def _count(self, name: str, wall: float, cpu: float):
+        total = self._totals.setdefault(name, [0, 0.0, 0.0])
+        total[0] += 1
+        total[1] += wall
+        total[2] += cpu
+
+    def totals(self) -> dict:
+        """``{phase: {"count", "wall_s", "cpu_s"}}`` summed since this
+        object was built (``record_iteration`` does not clear them), plus
+        ``"gc"`` once :meth:`gc_span` or a profile has traced collections."""
+        return {k: {"count": n, "wall_s": w, "cpu_s": c}
+                for k, (n, w, c) in self._totals.items()}
+
+    def _on_gc(self, phase: str, info):
+        if phase == "start":
+            ann = jax.profiler.TraceAnnotation("gc")
+            ann.__enter__()
+            self._gc_open = (ann, time.perf_counter(), time.thread_time())
+        elif self._gc_open is not None:
+            ann, t0, c0 = self._gc_open
+            self._gc_open = None
+            ann.__exit__(None, None, None)
+            self._count("gc", time.perf_counter() - t0,
+                        time.thread_time() - c0)
+
+    @contextmanager
+    def gc_span(self):
+        """Inside the block every Python garbage collection is a profiler
+        annotation ``gc`` and counts into ``totals()["gc"]``.  Meant for
+        the span of a profiler trace (``start_profile`` turns it on), so
+        that it costs nothing when no trace is taken."""
+        self._trace_gc(True)
         try:
             yield
         finally:
-            dt = time.perf_counter() - t0
-            self._phases[name] = self._phases.get(name, 0.0) + dt
+            self._trace_gc(False)
+
+    def _trace_gc(self, on: bool):
+        if on:
+            self._totals.setdefault("gc", [0, 0.0, 0.0])
+            gc.callbacks.append(self._on_gc)
+        else:
+            gc.callbacks.remove(self._on_gc)
+            self._gc_open = None
 
     def block(self, name: str, value):
         """The other half of the dispatch/block split: wait for ``value``'s
@@ -140,11 +202,13 @@ class RunTelemetry:
         whatever the iteration produced.  ``metrics``/``stats`` may be jax
         arrays — passed by reference, fetched on the sink thread."""
         phases = {k: round(v, 6) for k, v in self._phases.items()}
+        phases_cpu = {k: round(v, 6) for k, v in self._phases_cpu.items()}
         self._phases.clear()
+        self._phases_cpu.clear()
         if self._compile_label == "warmup":
             self._compile_label = "steady"
         row = {"kind": "iter", "t": self._stamp(), "step": step,
-               "phases": phases, **extra}
+               "phases": phases, "phases_cpu": phases_cpu, **extra}
         if self._blocks:
             row["blocks"] = {k: round(v, 6)
                              for k, v in self._blocks.items()}
@@ -209,16 +273,19 @@ class RunTelemetry:
 
     # ------------------------------------------------------------ profiler
     def start_profile(self, trace_dir):
-        """Begin a ``jax.profiler`` device trace into ``trace_dir``."""
+        """Begin a ``jax.profiler`` device trace into ``trace_dir``, with
+        garbage collections traced (:meth:`gc_span`) until it stops."""
         if self._profiling:
             return
         jax.profiler.start_trace(str(trace_dir))
+        self._trace_gc(True)
         self._profiling = True
         self.record("profile", action="start", dir=str(trace_dir))
 
     def stop_profile(self):
         if not self._profiling:
             return
+        self._trace_gc(False)
         jax.profiler.stop_trace()
         self._profiling = False
         self.record("profile", action="stop")

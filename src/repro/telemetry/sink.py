@@ -44,8 +44,12 @@ import numpy as np
 ROW_KINDS: dict[str, tuple] = {
     "run": ("run_id",),                      # header: config, devices, ...
     "iter": ("step", "phases"),              # per-iteration timings:
-    #   "phases" — host DISPATCH wall-clock per phase (time spent
-    #     enqueueing device work; never includes waiting on results);
+    #   "phases" — host wall-clock per phase around DISPATCH (enqueueing
+    #     device work; never a wait on results, but it includes any time
+    #     the runtime holds the call, e.g. until a running step frees the
+    #     buffers the next one needs);
+    #   "phases_cpu" — the thread's CPU time in the same phases: wall
+    #     minus CPU is the time the call was held rather than worked;
     #   "blocks" (optional) — host WAIT wall-clock per name
     #     (``RunTelemetry.block``: timed ``jax.block_until_ready``).
     #   Serial engine: block ≈ device wall per iteration.  Overlapped

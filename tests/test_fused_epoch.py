@@ -327,3 +327,18 @@ def test_islands_fused_update_matches_vectorized():
     _assert_trees_close(out["vectorized"], out["islands"],
                         "islands vs vectorized fused update",
                         rtol=1e-5, atol=1e-5)
+
+
+def test_fused_epoch_compiled_text_carries_its_scopes():
+    """The compiled epoch names its parts: collect, update, eval and
+    evolve appear as ``jax.named_scope`` in the instructions' op_name."""
+    import re
+    tr = _build("td3", "pbt")
+    r = tr.rollout
+    fn = tr._fused_epoch(4, 2, True)
+    text = fn.lower(tr.state, r.bufs, r.vstate, tr.hypers,
+                    tr.strategy.export_state(), tr.key).compile().as_text()
+    op_names = set(re.findall(r'op_name="([^"]*)"', text))
+    for scope in ("collect", "update", "eval", "evolve"):
+        assert any(re.search(rf"(^|/|\(){scope}(\)|/|$)", n)
+                   for n in op_names), scope

@@ -205,7 +205,6 @@ def test_lm_pbt_lineage_replays_through_report(tmp_path):
     tel = RunTelemetry(JSONLSink(log, strict=True),
                        meta={"arch": "rwkv6_test"})
     tr = PopTrainer(LMAgent(CFG, TCFG), pcfg, seed=0, telemetry=tel)
-    tr.tokens_per_step = 2 * 32
     for i in range(6):
         tr.step(_batch(4, seed=i))
     tel.close()
@@ -219,9 +218,37 @@ def test_lm_pbt_lineage_replays_through_report(tmp_path):
     # hyper trajectories carry the LM tuning set end to end
     traj = report.hyper_trajectories(rows)
     assert {"lr_scale", "weight_decay", "warmup_frac"} <= set(traj)
-    # dispatch-rate throughput lands in the iter rows (first iter has no
-    # previous dispatch timestamp, so >= 4 of 6)
+    # each iter row carries the host CPU time of its phases beside their
+    # wall time: the update call nested in the whole step, the evolve on
+    # its cadence, and no more CPU time than wall time (clock granularity
+    # aside)
     iters = [r for r in rows if r["kind"] == "iter"]
-    with_tps = [r for r in iters if "tokens_per_sec_per_member" in r]
-    assert len(with_tps) >= 4
-    assert all(r["tokens_per_sec_per_member"] > 0 for r in with_tps)
+    assert len(iters) == 6
+    for r in iters:
+        assert {"step", "update"} <= set(r["phases_cpu"])
+        assert set(r["phases_cpu"]) == set(r["phases"])
+        assert all(0 <= cpu <= r["phases"][k] + 1e-3
+                   for k, cpu in r["phases_cpu"].items())
+        assert r["phases_cpu"]["update"] <= r["phases_cpu"]["step"] + 1e-3
+    assert sum("evolve" in r["phases_cpu"] for r in iters) == 3
+
+
+# ------------------------------------------------- device scopes of the step
+def test_lm_step_compiled_text_carries_the_four_scopes():
+    """The qwen2 step at smoke shapes: every scope names instructions of
+    the compiled program, the layer scans' ``while`` loops among them
+    (forward and backward), so a profiler trace can be split by them."""
+    import re
+    cfg = get_config("qwen2_0_5b").smoke()
+    agent = LMAgent(cfg, TCFG)
+    state = agent.population_init(jax.random.PRNGKey(0), 1)
+    batch = {"tokens": jnp.zeros((1, 2, 16), jnp.int32)}
+    text = make_update(agent, "vectorized", donate=False).lower(
+        state, batch, None).compile().as_text()
+    op_names = re.findall(r'op_name="([^"]*)"', text)
+    for scope in ("embed", "layers", "head", "optimizer"):
+        assert any(f"({scope})" in n or f"/{scope}/" in n
+                   for n in op_names), scope
+    loops = re.findall(r'while\(.*op_name="([^"]*)"', text)
+    assert any("jvp(layers))/while" in n for n in loops), loops
+    assert any("transpose(jvp(layers))" in n for n in loops), loops

@@ -192,9 +192,106 @@ def test_phase_timers_accumulate_and_clear(tmp_path):
     assert rows[0]["phases"]["update"] >= 0.02
     assert rows[0]["phases"]["evolve"] >= 0.005
     assert rows[1]["phases"] == {}
+    # CPU time beside wall time: a sleeping phase barely uses the CPU
+    assert set(rows[0]["phases_cpu"]) == {"update", "evolve"}
+    assert rows[0]["phases_cpu"]["update"] < rows[0]["phases"]["update"]
+    assert rows[1]["phases_cpu"] == {}
     # row timestamps are monotone within one producer
     ts = [r["t"] for r in report.load_rows(tmp_path / "t.jsonl")]
     assert ts == sorted(ts)
+
+
+def _spin(secs):
+    t = time.perf_counter()
+    while time.perf_counter() - t < secs:
+        pass
+
+
+def test_totals_split_wall_into_cpu_and_blocked():
+    """``totals()`` is cumulative across ``record_iteration`` and splits a
+    phase's wall time into this thread's CPU time and the rest, the time
+    it waited: a sleeping phase reads CPU ~ 0 and blocked ~ the sleep, a
+    spinning one CPU ~ wall."""
+    tel = RunTelemetry(None)
+    with tel.phase("sleep"):
+        time.sleep(0.05)
+    tel.record_iteration(0)
+    with tel.phase("spin"):
+        _spin(0.05)
+    with tel.phase("sleep"):
+        time.sleep(0.05)
+    tel.record_iteration(1)
+    tot = tel.totals()
+    assert set(tot) == {"sleep", "spin"}
+    sleep, spin = tot["sleep"], tot["spin"]
+    assert sleep["count"] == 2 and spin["count"] == 1
+    assert sleep["wall_s"] >= 0.1 and spin["wall_s"] >= 0.05
+    assert sleep["cpu_s"] < 0.25 * sleep["wall_s"]
+    assert sleep["wall_s"] - sleep["cpu_s"] >= 0.09
+    # a loaded host may take the CPU from the spinning thread now and then
+    assert spin["cpu_s"] >= 0.5 * spin["wall_s"]
+    assert tel.totals() == tot   # reading does not clear
+
+
+def _trace_events(trace_dir, names):
+    """``{name: [(start_ns, end_ns)]}`` of the host events named in
+    ``names`` in the profiler trace under ``trace_dir``."""
+    from jax.profiler import ProfileData
+    (path,) = Path(trace_dir).glob("plugins/profile/*/*.xplane.pb")
+    out = {}
+    for plane in ProfileData.from_file(str(path)).planes:
+        for line in plane.lines:
+            for e in line.events:
+                if e.name in names:
+                    out.setdefault(e.name, []).append(
+                        (e.start_ns, e.start_ns + e.duration_ns))
+    return out
+
+
+def test_phases_are_trace_annotations_update_inside_step(tmp_path):
+    """Each phase is a profiler annotation ``pop.<phase>`` on the trace's
+    clock — with a disabled telemetry too, which is what a trainer built
+    without one has — and ``update`` lies inside ``step``."""
+    tel = RunTelemetry(None)
+    f = jax.jit(lambda x: jnp.sin(x).sum())
+    x = jnp.ones((64,))
+    f(x).block_until_ready()
+    jax.profiler.start_trace(str(tmp_path))
+    with tel.phase("step"):
+        with tel.phase("update"):
+            f(x).block_until_ready()
+        _spin(0.002)
+    jax.profiler.stop_trace()
+    ev = _trace_events(tmp_path, {"pop.step", "pop.update", "step",
+                                  "update"})
+    assert set(ev) == {"pop.step", "pop.update"}
+    ((s0, s1),), ((u0, u1),) = ev["pop.step"], ev["pop.update"]
+    assert s0 <= u0 < u1 <= s1 and u1 - u0 < s1 - s0
+
+
+def test_gc_span_records_a_forced_collection(tmp_path):
+    """Inside ``gc_span`` a collection is a ``gc`` annotation in the trace
+    and a count in ``totals()``; outside it nothing is hooked."""
+    import gc
+    tel = RunTelemetry(None)
+    jax.profiler.start_trace(str(tmp_path))
+    with tel.gc_span():
+        gc.collect()
+    jax.profiler.stop_trace()
+    assert tel._on_gc not in gc.callbacks
+    gc.collect()
+    g = tel.totals()["gc"]
+    assert g["count"] >= 1 and g["wall_s"] > 0
+    assert len(_trace_events(tmp_path, {"gc"})["gc"]) == g["count"]
+    # a profile traces collections until it stops
+    tel.start_profile(tmp_path / "profile")
+    gc.collect()
+    tel.stop_profile()
+    n = tel.totals()["gc"]["count"]
+    assert n > g["count"]
+    gc.collect()
+    assert tel.totals()["gc"]["count"] == n
+    assert tel._on_gc not in gc.callbacks
 
 
 def test_compile_listener_counts_labels_and_unregisters(tmp_path):
@@ -293,6 +390,15 @@ def test_pbt_log_phase_timings_reconstruct(pbt_log):
     assert phases["eval"]["iters"] == 6
     assert phases["evolve"]["iters"] == 3
     assert all(d["secs"] > 0 for d in phases.values())
+    # each phase's wall time splits into CPU and blocked time
+    for d in phases.values():
+        assert 0 <= d["cpu_secs"] <= d["secs"] + 1e-3
+        assert d["cpu_secs"] + d["blocked_secs"] == \
+            pytest.approx(d["secs"], abs=1e-3)
+    assert report.phase_summary(
+        [{"kind": "iter", "step": 0, "phases": {"u": 0.5}}]) == \
+        {"u": {"secs": 0.5, "iters": 1, "ms_per_iter": 500.0,
+               "cpu_secs": None, "blocked_secs": None}}
     iters = [r for r in pbt_log if r["kind"] == "iter"]
     assert [r["step"] for r in iters] == list(range(6))
     assert all(isinstance(r["metrics"]["critic_loss"], list)
@@ -353,6 +459,9 @@ def test_report_renders_and_check_passes(pbt_log, tmp_path, capsys):
     for section in ("phases", "compiles", "family tree", "lineage",
                     "hyper actor_lr", "checkpoints"):
         assert section in text
+    iterate = next(line for line in text.splitlines()
+                   if line.strip().startswith("iterate "))
+    assert "cpu" in iterate and "blocked" in iterate
     # --check exit codes: 0 on the real log, 1 when a row is broken
     p = tmp_path / "log.jsonl"
     p.write_text("\n".join(json.dumps(r) for r in pbt_log) + "\n")

@@ -169,9 +169,20 @@ def _timer_summary(rows, field):
 
 
 def phase_summary(rows):
-    """``{phase: {"secs": total, "iters": n, "ms_per_iter": mean}}`` over
-    the iter rows' ``phases`` (host DISPATCH time per phase)."""
-    return _timer_summary(rows, "phases")
+    """``{phase: {"secs": total, "iters": n, "ms_per_iter": mean,
+    "cpu_secs": total, "blocked_secs": total}}`` over the iter rows'
+    ``phases`` (host DISPATCH wall time per phase) and ``phases_cpu`` (the
+    thread's CPU time in it; blocked is wall less CPU, the time the host
+    waited).  The CPU and blocked totals are None for a log whose rows
+    carry no ``phases_cpu``."""
+    out = _timer_summary(rows, "phases")
+    cpu = _timer_summary(rows, "phases_cpu")
+    for name, d in out.items():
+        c = cpu.get(name)
+        d["cpu_secs"] = None if c is None else c["secs"]
+        d["blocked_secs"] = None if c is None \
+            else round(d["secs"] - c["secs"], 4)
+    return out
 
 
 def block_summary(rows):
@@ -236,11 +247,16 @@ def report(rows, out=None) -> None:
     phases = phase_summary(rows)
     if phases:
         iters = by_kind(rows, "iter")
-        w(f"\nphases ({len(iters)} iterations; dispatch time)\n")
+        w(f"\nphases ({len(iters)} iterations; host dispatch time: wall "
+          f"= cpu + blocked)\n")
         for name, d in sorted(phases.items(), key=lambda kv:
                               -kv[1]["secs"]):
+            split = "" if d["cpu_secs"] is None else \
+                f"  cpu {d['cpu_secs']:>8.3f}s  blocked " \
+                f"{d['blocked_secs']:>8.3f}s"
             w(f"  {name:<10} {d['secs']:>9.3f}s total  "
-              f"{d['ms_per_iter']:>9.3f} ms/iter  ({d['iters']} iters)\n")
+              f"{d['ms_per_iter']:>9.3f} ms/iter{split}  "
+              f"({d['iters']} iters)\n")
 
     blocks = block_summary(rows)
     if blocks:
