@@ -84,14 +84,15 @@ def attention_fn(use_kernels=None):
         return None
 
     def attn(q, k, v, positions, kv_positions, *, causal=True, scale=None):
-        s = q.shape[1]
+        b, s, h, _ = q.shape
         if s > 128 and s % 128:  # kernel block constraint: fall back
             from repro.nn.attention import sdpa_auto
             return sdpa_auto(q, k, v, positions, kv_positions, causal=causal,
                              scale=scale)
         y = flash_attention(jnp.moveaxis(q, 1, 2), jnp.moveaxis(k, 1, 2),
                             jnp.moveaxis(v, 1, 2), causal=causal)
-        return jnp.moveaxis(y, 1, 2)
+        # (B,S,H*D), as sdpa returns it for the output projection
+        return jnp.moveaxis(y, 1, 2).reshape(b, s, h * v.shape[-1])
 
     return attn
 

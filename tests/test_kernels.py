@@ -21,10 +21,12 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from repro.configs import get_config
 from repro.kernels import ops, ref
 from repro.kernels.pop_adam import VMEM_BUDGET, pop_adam
 from repro.kernels.pop_adam import tiles as pop_adam_tiles
 from repro.kernels.pop_matmul import supports_shapes
+from repro.models import lm as L
 from repro.nn.basic import mlp_init, mlp_apply
 from repro.rl import networks as nets
 
@@ -251,6 +253,20 @@ def test_flash_attention_non_causal():
        causal=st.booleans())
 def test_flash_attention_property(b, g, hkv, s, d, causal):
     _flash_parity(b, g * hkv, hkv, s, d, jnp.float32, causal)
+
+
+@pytest.mark.parametrize("seq", [128, 256])
+def test_lm_forward_through_flash_kernel_matches_sdpa(seq):
+    """An LM forward that routes attention through the flash kernel (as
+    inference does on a TPU) gives sdpa's logits: the kernel path hands
+    the output projection (B, S, H*D), as sdpa does."""
+    cfg = get_config("qwen2_0_5b").smoke()
+    params = L.init_params(jax.random.PRNGKey(0), cfg)
+    batch = {"tokens": jax.random.randint(KEY, (1, seq), 0, cfg.vocab_size)}
+    want, _, _ = L.forward(params, cfg, batch)
+    got, _, _ = L.forward(params, cfg.replace(use_kernels=True), batch)
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                               **TOL[jnp.float32])
 
 
 # ------------------------------------------- population-batched applies
