@@ -45,10 +45,19 @@ class LMConfig:
     activation: str = "silu"       # silu -> SwiGLU, gelu -> GeGLU
     rope_theta: float = 10000.0
     tie_embeddings: bool = False
-    block_type: str = "attention"  # attention | rwkv6 | mamba2
+    block_type: str = "attention"  # attention | rwkv6 | mamba2 | hybrid
+    layer_types: tuple = ()        # hybrid: each layer's mixer, "mamba" or
+                                   # "attention", each followed by an MLP
     ssm_state: int = 0
     ssm_head_dim: int = 64
     shared_attn_every: int = 0     # zamba2: shared attn block period (0 = off)
+    norm_eps: float = 1e-6         # every RMSNorm, Mamba-2's gated one too
+    position_embedding: str = "rope"  # rope | nope (no positional encoding)
+    attention_multiplier: Optional[float] = None  # softmax scale; None =
+                                                  # head_dim ** -0.5
+    embedding_multiplier: float = 1.0  # muP: token embeddings scaled by it
+    residual_multiplier: float = 1.0   # muP: each branch scaled before its add
+    logits_scaling: float = 1.0        # muP: logits divided by it
     moe: Optional[MoESpec] = None
     mla: Optional[MLASpec] = None
     frontend: str = "none"         # none | audio_frames | vision_patches
@@ -99,9 +108,13 @@ class LMConfig:
             kw["shared_attn_every"] = 4
         if self.num_frontend_positions:
             kw["num_frontend_positions"] = 8
-        if self.block_type in ("rwkv6", "mamba2"):
+        if self.block_type in ("rwkv6", "mamba2", "hybrid"):
             kw["ssm_head_dim"] = 32
-            kw["ssm_state"] = 16 if self.block_type == "mamba2" else 0
+            kw["ssm_state"] = 0 if self.block_type == "rwkv6" else 16
+        if self.layer_types:
+            # both mixers, scanned over two periods
+            kw["num_layers"] = 4
+            kw["layer_types"] = ("mamba", "attention") * 2
         return self.replace(**kw)
 
 

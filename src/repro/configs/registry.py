@@ -6,7 +6,7 @@ import importlib
 _ARCHS = (
     "musicgen_medium", "qwen3_moe_30b_a3b", "deepseek_v2_lite_16b",
     "pixtral_12b", "rwkv6_1_6b", "zamba2_7b", "qwen2_1_5b", "qwen3_8b",
-    "gemma_7b", "qwen2_0_5b", "rwkv6_test",
+    "gemma_7b", "qwen2_0_5b", "rwkv6_test", "granite_4_0_h_micro",
 )
 
 

@@ -12,7 +12,7 @@ CONFIG = LMConfig(
     num_layers=81, d_model=3584, num_heads=32, num_kv_heads=32,
     d_ff=14336, vocab_size=32000,
     block_type="mamba2", ssm_state=64, ssm_head_dim=64,
-    shared_attn_every=6,
+    shared_attn_every=6, norm_eps=1e-5,
     # §Perf: bf16 intra-chunk SSD + chunk 64 (see EXPERIMENTS.md zamba2 log)
     ssm_chunk=256, ssm_compute_dtype="bfloat16",
 )

@@ -37,9 +37,10 @@ def pop_adam(params, grads, mu, nu, lr, step, *, interpret=None):
                         interpret=_auto_interpret(interpret))
 
 
-@partial(jax.jit, static_argnames=("causal", "interpret"))
-def flash_attention(q, k, v, *, causal: bool = True, interpret=None):
-    return _fa.flash_attention(q, k, v, causal=causal,
+@partial(jax.jit, static_argnames=("causal", "scale", "interpret"))
+def flash_attention(q, k, v, *, causal: bool = True, scale=None,
+                    interpret=None):
+    return _fa.flash_attention(q, k, v, causal=causal, scale=scale,
                                interpret=_auto_interpret(interpret))
 
 
@@ -90,7 +91,8 @@ def attention_fn(use_kernels=None):
             return sdpa_auto(q, k, v, positions, kv_positions, causal=causal,
                              scale=scale)
         y = flash_attention(jnp.moveaxis(q, 1, 2), jnp.moveaxis(k, 1, 2),
-                            jnp.moveaxis(v, 1, 2), causal=causal)
+                            jnp.moveaxis(v, 1, 2), causal=causal,
+                            scale=scale)
         # (B,S,H*D), as sdpa returns it for the output projection
         return jnp.moveaxis(y, 1, 2).reshape(b, s, h * v.shape[-1])
 

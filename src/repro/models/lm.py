@@ -5,6 +5,8 @@ One config-driven model family:
     musicgen, qwen3-moe, deepseek-v2-lite)
   * RWKV6 (attention-free)
   * Mamba2 (+ Zamba2 shared-attention hybrid)
+  * hybrid stacks whose layers each pick a mixer (Mamba2 or attention) and
+    follow it with an MLP (granite-4.0-h), scanned one period at a time
 
 Structure is organised as *segments* of homogeneous blocks; each segment is a
 ``jax.lax.scan`` over stacked layer parameters (keeps the HLO small enough
@@ -51,11 +53,21 @@ from repro.optim import (adam, apply_updates, dynamic_warmup_cosine,
 @dataclass(frozen=True)
 class Segment:
     name: str
-    kind: str            # attn | rwkv | mamba
+    kind: str            # attn | rwkv | mamba | hybrid
     count: int           # scan length
     inner: int = 1       # mamba layers per scanned super-block
     moe: bool = False
     shared_attn: bool = False
+    pattern: tuple = ()  # hybrid: the mixers of one scanned period, in order
+
+
+def _period(types: tuple) -> tuple:
+    """The shortest prefix of ``types`` that repeats to make all of it."""
+    n = len(types)
+    for p in range(1, n + 1):
+        if n % p == 0 and types == types[:p] * (n // p):
+            return types[:p]
+    raise ValueError("empty layer_types")
 
 
 def layout(cfg: LMConfig) -> list[Segment]:
@@ -81,6 +93,15 @@ def layout(cfg: LMConfig) -> list[Segment]:
                                     shared_attn=True))
             return segs
         return [Segment("mamba", "mamba", cfg.num_layers)]
+    if cfg.block_type == "hybrid":
+        types = tuple(cfg.layer_types)
+        if len(types) != cfg.num_layers or \
+                not set(types) <= {"mamba", "attention"}:
+            raise ValueError(f"layer_types {types} for {cfg.num_layers} "
+                             "layers of mamba | attention")
+        period = _period(types)
+        return [Segment("hybrid", "hybrid", cfg.num_layers // len(period),
+                        pattern=period)]
     raise ValueError(cfg.block_type)
 
 
@@ -115,7 +136,7 @@ def _attn_block_init(key, cfg: LMConfig, moe_layer: bool):
 def _attn_block_apply(p, cfg: LMConfig, h, positions, cache, cache_index,
                       moe_layer: bool, use_kernels=False):
     p = constrain_tree(p)  # pins param+cotangent shardings inside the scan
-    y = rmsnorm_apply(p["attn_norm"], h)
+    y = rmsnorm_apply(p["attn_norm"], h, eps=cfg.norm_eps)
     if cfg.mla is not None:
         m = cfg.mla
         y, new_cache = mla_apply(
@@ -130,7 +151,7 @@ def _attn_block_apply(p, cfg: LMConfig, h, positions, cache, cache_index,
             rope_theta=cfg.rope_theta, cache=cache, cache_index=cache_index,
             attn_fn=kernel_ops.attention_fn(use_kernels))
     h = constrain(h + y, "F", "M", None)
-    y = rmsnorm_apply(p["mlp_norm"], h)
+    y = rmsnorm_apply(p["mlp_norm"], h, eps=cfg.norm_eps)
     if moe_layer:
         m = cfg.moe
         y, aux = moe_apply(p["mlp"], y, num_experts=m.num_experts, top_k=m.top_k,
@@ -186,22 +207,111 @@ def _mamba_layer_init(key, cfg: LMConfig):
                                        head_dim=cfg.ssm_head_dim)}
 
 
-def _mamba_layer_apply(p, cfg: LMConfig, h, state, use_kernels=False):
-    p = constrain_tree(p)
-    b = h.shape[0]
+def _mamba_mixer(p, cfg: LMConfig, x, state, use_kernels=False):
+    """The Mamba-2 mixer on the normed input ``x``; a zero state (a fresh
+    sequence) when ``state`` is None."""
     if state is None:
+        b = x.shape[0]
         d_inner = 2 * cfg.d_model
         nh = d_inner // cfg.ssm_head_dim
         state = {"ssm": jnp.zeros((b, nh, cfg.ssm_head_dim, cfg.ssm_state),
                                   jnp.float32),
-                 "conv": jnp.zeros((b, 3, d_inner + 2 * cfg.ssm_state), h.dtype)}
-    y, new_state = mamba2_block_apply(
-        p["mamba"], rmsnorm_apply(p["norm"], h), state,
-        d_state=cfg.ssm_state, head_dim=cfg.ssm_head_dim,
-        use_chunked=cfg.use_chunked, chunk=cfg.ssm_chunk,
-        compute_dtype=jnp.dtype(cfg.ssm_compute_dtype),
-        use_kernels=use_kernels)
+                 "conv": jnp.zeros((b, 3, d_inner + 2 * cfg.ssm_state), x.dtype)}
+    with jax.named_scope("mamba"):
+        return mamba2_block_apply(
+            p, x, state, d_state=cfg.ssm_state, head_dim=cfg.ssm_head_dim,
+            use_chunked=cfg.use_chunked, chunk=cfg.ssm_chunk,
+            compute_dtype=jnp.dtype(cfg.ssm_compute_dtype),
+            norm_eps=cfg.norm_eps, use_kernels=use_kernels)
+
+
+def _mamba_layer_apply(p, cfg: LMConfig, h, state, use_kernels=False):
+    p = constrain_tree(p)
+    y, new_state = _mamba_mixer(
+        p["mamba"], cfg, rmsnorm_apply(p["norm"], h, eps=cfg.norm_eps),
+        state, use_kernels)
     return constrain(h + y, "F", "M", None), new_state
+
+
+def _hybrid_layer_init(key, cfg: LMConfig, mixer: str):
+    k1, k2 = jax.random.split(key)
+    if mixer == "attention":
+        mix = gqa_init(k1, d_model=cfg.d_model, num_heads=cfg.num_heads,
+                       num_kv_heads=cfg.num_kv_heads, head_dim=cfg.hd,
+                       qkv_bias=cfg.qkv_bias, qk_norm=cfg.qk_norm)
+    else:
+        mix = mamba2_block_init(k1, d_model=cfg.d_model,
+                                d_state=cfg.ssm_state,
+                                head_dim=cfg.ssm_head_dim)
+    return {"norm": rmsnorm_init(cfg.d_model), "mixer": mix,
+            "mlp_norm": rmsnorm_init(cfg.d_model),
+            "mlp": glu_mlp_init(k2, cfg.d_model, cfg.d_ff)}
+
+
+def _hybrid_period_init(key, cfg: LMConfig, pattern: tuple):
+    """One period's layers, stacked per mixer: ``{mixer: (n, ...)}``."""
+    return {m: _stacked_init(jax.random.fold_in(key, i), pattern.count(m),
+                             partial(_hybrid_layer_init, cfg=cfg, mixer=m))
+            for i, m in enumerate(sorted(set(pattern)))}
+
+
+def _scaled(y, multiplier: float):
+    return y if multiplier == 1.0 else y * multiplier
+
+
+def _hybrid_layer_apply(p, cfg: LMConfig, mixer: str, h, positions, state,
+                        cache_index, use_kernels=False):
+    """One hybrid layer: ``h += r * mixer(rms(h))``, then ``h += r *
+    mlp(rms(h))``, ``r`` the residual multiplier.  ``state`` is the
+    mixer's decode state (a KV cache or the SSM and conv states)."""
+    p = constrain_tree(p)
+    y = rmsnorm_apply(p["norm"], h, eps=cfg.norm_eps)
+    if mixer == "attention":
+        with jax.named_scope("attention"):
+            y, new_state = gqa_apply(
+                p["mixer"], y, positions, num_heads=cfg.num_heads,
+                num_kv_heads=cfg.num_kv_heads, head_dim=cfg.hd,
+                rope_theta=cfg.rope_theta,
+                rope=cfg.position_embedding == "rope",
+                scale=cfg.attention_multiplier, cache=state,
+                cache_index=cache_index,
+                attn_fn=kernel_ops.attention_fn(use_kernels))
+    else:
+        y, new_state = _mamba_mixer(p["mixer"], cfg, y, state, use_kernels)
+    h = constrain(h + _scaled(y, cfg.residual_multiplier), "F", "M", None)
+    y = glu_mlp_apply(p["mlp"], rmsnorm_apply(p["mlp_norm"], h, eps=cfg.norm_eps),
+                      activation=cfg.activation)
+    h = constrain(h + _scaled(y, cfg.residual_multiplier), "F", "M", None)
+    return h, new_state
+
+
+def _hybrid_period_apply(seg: Segment, p, state, cfg: LMConfig, h, positions,
+                         cache_index, remat: bool, use_kernels=False):
+    """The layers of one period in ``seg.pattern`` order, each taking the
+    next layer of its mixer's stack.  With ``remat`` each layer is
+    rematerialised on its own, so the backward pass holds the activations
+    of one layer at a time, not of the whole period."""
+    taken = {m: 0 for m in p}
+    new = {m: [] for m in p}
+    for mixer in seg.pattern:
+        i = taken[mixer]
+        taken[mixer] += 1
+
+        def layer(p_m, h, st_m, i=i, mixer=mixer):
+            take = lambda t: jax.tree.map(lambda a: a[i], t)
+            return _hybrid_layer_apply(
+                take(p_m), cfg, mixer, h, positions,
+                None if st_m is None else take(st_m), cache_index,
+                use_kernels)
+
+        if remat:
+            layer = jax.checkpoint(layer)
+        h, st = layer(p[mixer], h, None if state is None else state[mixer])
+        new[mixer].append(st)
+    if state is None:
+        return h, None
+    return h, {m: jax.tree.map(lambda *xs: jnp.stack(xs), *sts)
+               for m, sts in new.items()}
 
 
 # ---------------------------------------------------------------------------
@@ -225,6 +335,9 @@ def init_params(key, cfg: LMConfig):
             params["segments"][seg.name] = _stacked_init(kseg, seg.count, fn)
         elif seg.kind == "rwkv":
             fn = partial(_rwkv_block_init, cfg=cfg)
+            params["segments"][seg.name] = _stacked_init(kseg, seg.count, fn)
+        elif seg.kind == "hybrid":
+            fn = partial(_hybrid_period_init, cfg=cfg, pattern=seg.pattern)
             params["segments"][seg.name] = _stacked_init(kseg, seg.count, fn)
         else:  # mamba / zamba super-blocks
             fn = partial(_mamba_layer_init, cfg=cfg)
@@ -267,6 +380,10 @@ def _segment_forward(seg: Segment, seg_params, shared_p, cfg: LMConfig, h,
                                           layer_st if collect_state else None,
                                           use_kernels)
             new_st = new_st if collect_state else None
+        elif seg.kind == "hybrid":
+            h, new_st = _hybrid_period_apply(
+                seg, layer_p, layer_st if collect_state else None, cfg, h,
+                positions, cache_index, cfg.remat and train, use_kernels)
         else:  # mamba (possibly zamba super-block with shared attention)
             if seg.shared_attn:
                 cache = layer_st["attn"]["kv"] if collect_state else None
@@ -293,7 +410,7 @@ def _segment_forward(seg: Segment, seg_params, shared_p, cfg: LMConfig, h,
                 new_st = new_st if collect_state else None
         return h, (new_st, aux)
 
-    if cfg.remat and train:
+    if cfg.remat and train and seg.kind != "hybrid":  # hybrid: per layer
         body = jax.checkpoint(body)
     # the scope wraps the scan call, not only its body, so that the loop
     # instruction itself carries it
@@ -325,6 +442,7 @@ def forward(params, cfg: LMConfig, batch, state=None, cache_index=None,
                     [batch["patch_embeds"].astype(dtype), h[:, npatch:]], axis=1)
     if cfg.family == "dense" and cfg.name.startswith("gemma"):
         h = h * jnp.asarray(cfg.d_model ** 0.5, dtype)
+    h = _scaled(h, cfg.embedding_multiplier)
 
     if cache_index is None:
         positions = jnp.broadcast_to(jnp.arange(s, dtype=jnp.int32), (b, s))
@@ -352,11 +470,19 @@ def forward(params, cfg: LMConfig, batch, state=None, cache_index=None,
         aux_total = aux_total + aux
 
     with jax.named_scope("head"):
-        h = rmsnorm_apply(params["final_norm"], h)
+        h = rmsnorm_apply(params["final_norm"], h, eps=cfg.norm_eps)
         if return_hidden:
             return h, new_state, aux_total
-        logits = h @ _head_weight(cparams, cfg)
+        logits = _logits(h, _head_weight(cparams, cfg), cfg)
     return logits, new_state, aux_total
+
+
+def _logits(h, w, cfg: LMConfig):
+    """The head: the final hidden state against ``w``, over
+    ``logits_scaling``."""
+    logits = h @ w
+    return logits if cfg.logits_scaling == 1.0 else \
+        logits / cfg.logits_scaling
 
 
 def _head_weight(cparams, cfg: LMConfig):
@@ -401,7 +527,7 @@ def lm_loss(params, cfg: LMConfig, batch, train: bool = True):
             nc = hidden.shape[1] // cfg.logits_chunk
             def body(carry, xs):
                 h_c, l_c, m_c = xs
-                ce, n = _token_ce(h_c @ w, l_c, m_c)
+                ce, n = _token_ce(_logits(h_c, w, cfg), l_c, m_c)
                 return (carry[0] + ce, carry[1] + n), None
             reshape = lambda x: jnp.moveaxis(x.reshape(
                 x.shape[0], nc, cfg.logits_chunk, *x.shape[2:]), 1, 0)
@@ -409,7 +535,7 @@ def lm_loss(params, cfg: LMConfig, batch, train: bool = True):
                 body, (jnp.zeros(()), jnp.zeros(())),
                 (reshape(hidden), reshape(labels), reshape(mask)))
         else:
-            ce, n = _token_ce(hidden @ w, labels, mask)
+            ce, n = _token_ce(_logits(hidden, w, cfg), labels, mask)
     loss = ce / jnp.maximum(n, 1.0)
     if cfg.moe is not None:
         loss = loss + cfg.moe.aux_loss_weight * aux / max(
@@ -537,15 +663,18 @@ def make_serve_step(cfg: LMConfig):
 # ---------------------------------------------------------------------------
 
 
+def _is_shape(x):
+    return isinstance(x, tuple) and len(x) == 2 and isinstance(x[0], tuple)
+
+
 def _seg_state_shape(seg: Segment, cfg: LMConfig, batch: int, max_len: int):
     dtype = jnp.dtype(cfg.dtype)
-    if seg.kind == "attn" or seg.shared_attn:
-        if cfg.mla is not None and seg.kind == "attn":
-            attn = {"c_kv": ((batch, max_len, cfg.mla.kv_lora_rank), dtype),
-                    "k_rope": ((batch, max_len, cfg.mla.qk_rope_dim), dtype)}
-        else:
-            attn = {"k": ((batch, max_len, cfg.num_kv_heads, cfg.hd), dtype),
-                    "v": ((batch, max_len, cfg.num_kv_heads, cfg.hd), dtype)}
+    if cfg.mla is not None and seg.kind == "attn":
+        attn = {"c_kv": ((batch, max_len, cfg.mla.kv_lora_rank), dtype),
+                "k_rope": ((batch, max_len, cfg.mla.qk_rope_dim), dtype)}
+    else:
+        attn = {"k": ((batch, max_len, cfg.num_kv_heads, cfg.hd), dtype),
+                "v": ((batch, max_len, cfg.num_kv_heads, cfg.hd), dtype)}
     if seg.kind == "attn":
         return {"kv": attn}
     if seg.kind == "rwkv":
@@ -558,18 +687,19 @@ def _seg_state_shape(seg: Segment, cfg: LMConfig, batch: int, max_len: int):
     nh = d_inner // cfg.ssm_head_dim
     mamba = {"ssm": ((batch, nh, cfg.ssm_head_dim, cfg.ssm_state), jnp.float32),
              "conv": ((batch, 3, d_inner + 2 * cfg.ssm_state), dtype)}
+    stack = lambda tree, n: jax.tree.map(lambda t: ((n,) + t[0], t[1]), tree,
+                                         is_leaf=_is_shape)
+    if seg.kind == "hybrid":
+        per = {"mamba": mamba, "attention": attn}
+        return {m: stack(per[m], seg.pattern.count(m))
+                for m in sorted(set(seg.pattern))}
     if seg.shared_attn:
-        mamba = {"mamba": jax.tree.map(
-            lambda t: ((seg.inner,) + t[0], t[1]), mamba,
-            is_leaf=lambda x: isinstance(x, tuple) and isinstance(x[0], tuple)),
-            "attn": {"kv": attn}}
+        mamba = {"mamba": stack(mamba, seg.inner), "attn": {"kv": attn}}
     return mamba
 
 
 def _materialize(tree, make):
-    is_shape = lambda x: (isinstance(x, tuple) and len(x) == 2
-                          and isinstance(x[0], tuple))
-    return jax.tree.map(lambda t: make(t[0], t[1]), tree, is_leaf=is_shape)
+    return jax.tree.map(lambda t: make(t[0], t[1]), tree, is_leaf=_is_shape)
 
 
 def decode_state_shapes(cfg: LMConfig, batch: int, max_len: int):
@@ -583,18 +713,14 @@ def decode_state_shapes(cfg: LMConfig, batch: int, max_len: int):
 
 def init_decode_state(cfg: LMConfig, batch: int, max_len: int):
     shapes = decode_state_shapes(cfg, batch, max_len)
-    is_shape = lambda x: (isinstance(x, tuple) and len(x) == 2
-                          and isinstance(x[0], tuple))
     return jax.tree.map(lambda t: jnp.zeros(t[0], t[1]), shapes,
-                        is_leaf=is_shape)
+                        is_leaf=_is_shape)
 
 
 def decode_state_specs(cfg: LMConfig, batch: int, max_len: int):
     shapes = decode_state_shapes(cfg, batch, max_len)
-    is_shape = lambda x: (isinstance(x, tuple) and len(x) == 2
-                          and isinstance(x[0], tuple))
     return jax.tree.map(lambda t: jax.ShapeDtypeStruct(t[0], t[1]), shapes,
-                        is_leaf=is_shape)
+                        is_leaf=_is_shape)
 
 
 def input_specs(cfg: LMConfig, shape: ShapeSpec):

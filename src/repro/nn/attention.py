@@ -120,11 +120,15 @@ def gqa_init_cache(batch: int, max_len: int, num_kv_heads: int, head_dim: int,
 
 
 def gqa_apply(p, x, positions, *, num_heads: int, num_kv_heads: int,
-              head_dim: int, rope_theta: float = 10000.0,
-              cache=None, cache_index=None, attn_fn=None):
+              head_dim: int, rope_theta: float = 10000.0, rope: bool = True,
+              scale: float | None = None, cache=None, cache_index=None,
+              attn_fn=None):
     """x: (B,S,Dm). If ``cache`` given, S is the new-token count (decode) and
-    ``cache_index`` the current fill level; returns (out, new_cache)."""
+    ``cache_index`` the current fill level; returns (out, new_cache).
+    ``rope=False`` leaves positions out of q and k (NoPE); ``scale`` is the
+    softmax scale, ``head_dim ** -0.5`` when None."""
     b, s, _ = x.shape
+    scale = head_dim ** -0.5 if scale is None else scale
 
     def proj(name, nh):
         y = x @ p[name]["w"]
@@ -138,8 +142,9 @@ def gqa_apply(p, x, positions, *, num_heads: int, num_kv_heads: int,
     if "q_norm" in p:
         q = rmsnorm_apply(p["q_norm"], q)
         k = rmsnorm_apply(p["k_norm"], k)
-    q = apply_rope(q, positions, theta=rope_theta)
-    k = apply_rope(k, positions, theta=rope_theta)
+    if rope:
+        q = apply_rope(q, positions, theta=rope_theta)
+        k = apply_rope(k, positions, theta=rope_theta)
 
     if cache is None:
         # sequence-parallel -> head-parallel relayout ONCE per layer (the
@@ -158,7 +163,7 @@ def gqa_apply(p, x, positions, *, num_heads: int, num_kv_heads: int,
             v = constrain(v, "F", "M", None, None)
         kv_positions = positions
         out = (attn_fn or sdpa_auto)(q, k, v, positions, kv_positions,
-                                     causal=True, scale=head_dim ** -0.5)
+                                     causal=True, scale=scale)
         out = constrain(out, "F", None, "M")
         return out @ p["wo"]["w"], None
 
@@ -172,7 +177,7 @@ def gqa_apply(p, x, positions, *, num_heads: int, num_kv_heads: int,
     # positions beyond the fill level are masked by causality (q position ==
     # cache_index + offset >= any unwritten slot index only if slot <= qpos).
     out = sdpa(q, new_cache["k"].astype(q.dtype), new_cache["v"].astype(q.dtype),
-               positions, kv_positions, causal=True, scale=head_dim ** -0.5)
+               positions, kv_positions, causal=True, scale=scale)
     return out @ p["wo"]["w"], new_cache
 
 

@@ -1,4 +1,5 @@
-"""Mamba-2 blocks (state-space duality / SSD), used by zamba2-7b.
+"""Mamba-2 blocks (state-space duality / SSD), used by zamba2-7b and
+granite-4.0-h-micro.
 
 Recurrence per head (head dim P, state dim N):
     h_t = exp(a * dt_t) h_{t-1} + dt_t * x_t B_t^T        h: (P, N)
@@ -144,8 +145,9 @@ def _causal_conv(w, bias, x, x_prev):
 def mamba2_block_apply(p, x, state, *, d_state: int = 64, head_dim: int = 64,
                        expand: int = 2, use_chunked: bool = True,
                        chunk: int = 128, compute_dtype=jnp.float32,
-                       use_kernels=None):
-    """x: (B,S,D); state from ``mamba2_init_state``. Returns (y, new_state)."""
+                       norm_eps: float, use_kernels=None):
+    """x: (B,S,D); state from ``mamba2_init_state``. Returns (y, new_state).
+    ``norm_eps`` is the gated RMSNorm's epsilon."""
     bsz, s, d_model = x.shape
     d_inner = expand * d_model
     n_heads = d_inner // head_dim
@@ -175,9 +177,11 @@ def mamba2_block_apply(p, x, state, *, d_state: int = 64, head_dim: int = 64,
 
     x32, b32, c32 = (t.astype(jnp.float32) for t in (xh, b, c))
     from repro.kernels.ops import ssd_apply  # lazy: ops falls back to us
-    y, ssm = ssd_apply(x32, dt, a, b32, c32, state["ssm"],
-                       use_chunked=use_chunked, chunk=chunk,
-                       compute_dtype=compute_dtype, use_kernels=use_kernels)
+    with jax.named_scope("ssd"):
+        y, ssm = ssd_apply(x32, dt, a, b32, c32, state["ssm"],
+                           use_chunked=use_chunked, chunk=chunk,
+                           compute_dtype=compute_dtype,
+                           use_kernels=use_kernels)
     y = y + p["d_skip"][:, None] * x32
     y = constrain(y, "F", None, "M", None)
     y = y.reshape(bsz, s, d_inner).astype(x.dtype)
@@ -185,6 +189,6 @@ def mamba2_block_apply(p, x, state, *, d_state: int = 64, head_dim: int = 64,
     # gated RMSNorm then out-projection
     y = y * jax.nn.silu(z)
     var = jnp.mean(jnp.square(y.astype(jnp.float32)), axis=-1, keepdims=True)
-    y = (y.astype(jnp.float32) * jax.lax.rsqrt(var + 1e-6)
+    y = (y.astype(jnp.float32) * jax.lax.rsqrt(var + norm_eps)
          * p["norm"]["scale"]).astype(x.dtype)
     return y @ p["out_proj"]["w"].astype(x.dtype), {"ssm": ssm, "conv": conv_state}

@@ -22,6 +22,7 @@ PUBLISHED = {
     "zamba2_7b": 7.2e9,
     "pixtral_12b": 12.4e9,
     "musicgen_medium": 1.5e9,
+    "granite_4_0_h_micro": 3.0e9,
 }
 
 

@@ -84,6 +84,7 @@ def test_full_configs_match_assignment():
         "qwen3_8b": (36, 4096, 32, 8, 12288, 151936),
         "gemma_7b": (28, 3072, 16, 16, 24576, 256000),
         "qwen2_0_5b": (24, 896, 14, 2, 4864, 151936),
+        "granite_4_0_h_micro": (40, 2048, 32, 8, 8192, 100352),
     }
     for arch, (nl, dm, nh, kv, ff, vs) in spec.items():
         c = get_config(arch)
@@ -95,6 +96,10 @@ def test_full_configs_match_assignment():
     assert get_config("deepseek_v2_lite_16b").mla.kv_lora_rank == 512
     assert get_config("zamba2_7b").ssm_state == 64
     assert get_config("gemma_7b").hd == 256
+    granite = get_config("granite_4_0_h_micro")
+    assert granite.ssm_state == 128
+    assert granite.layer_types == (
+        ("mamba",) * 5 + ("attention",) + ("mamba",) * 4) * 4
 
 
 def test_logits_chunk_loss_equivalence():
