@@ -277,7 +277,7 @@ def _kernel_cases():
     cfg = get_config("zamba2_7b")
     pd, nd, s, h, chunk = cfg.ssm_head_dim, cfg.ssm_state, 1024, 8, \
         cfg.ssm_chunk
-    args = (normal(1, h, s, pd), np.log1p(np.exp(normal(1, h, s))),
+    args = (normal(1, s, h, pd), np.log1p(np.exp(normal(1, s, h))),
             -np.exp(normal(h) * 0.3), normal(1, s, nd), normal(1, s, nd),
             normal(1, h, pd, nd) * 0.1)
     cases.append((f"ssd H={h} P={pd} N={nd} S={s} chunk={chunk}",

@@ -8,6 +8,7 @@ path so CPU cost_analysis stays well-defined (see DESIGN.md §5).
 from __future__ import annotations
 
 from functools import partial
+from typing import NamedTuple, Optional
 
 import jax
 import jax.numpy as jnp
@@ -52,6 +53,7 @@ def wkv6(r, k, v, lw, u, initial_state, *, chunk: int = 64, interpret=None):
 
 @partial(jax.jit, static_argnames=("chunk", "interpret"))
 def ssd(x, dt, a, b, c, initial_state, *, chunk: int = 128, interpret=None):
+    """The nn layout: x (B,S,H,P), dt (B,S,H), b/c (B,S,N)."""
     return _ssd.ssd(x, dt, a, b, c, initial_state, chunk=chunk,
                     interpret=_auto_interpret(interpret))
 
@@ -63,10 +65,30 @@ def ssd(x, dt, a, b, c, initial_state, *, chunk: int = 128, interpret=None):
 # implementations; these dispatchers route the hot op through the Pallas
 # kernel when enabled and otherwise call the EXACT nn fallback, so flipping
 # the flag never changes off-kernel numerics (tests pin the fallback path
-# bitwise).  The kernels carry no custom VJPs, so "auto" (None) resolves to
-# kernels only on TPU backends and callers gate them off for differentiated
-# (training) forwards.
+# bitwise).  "Auto" (None) means kernels only on TPU backends.  Only ``ssd``
+# has a custom VJP, so :func:`resolve_kernels` keeps flash attention and
+# wkv6 off in differentiated (training) forwards.
 # ---------------------------------------------------------------------------
+
+
+class KernelUse(NamedTuple):
+    """The tri-state flag of each kernel the LM forward dispatches to."""
+    attention: Optional[bool] = False
+    wkv6: Optional[bool] = False
+    ssd: Optional[bool] = False
+
+
+def resolve_kernels(flag=None, *, train: bool, use_flash: bool = False):
+    """Per-kernel flags from the model's tri-state ``use_kernels``.  An
+    explicit flag holds for every kernel.  Auto (None): in training only
+    ``ssd`` stays auto, since it alone trains through its kernel; otherwise
+    every kernel is auto, or on wherever ``use_flash`` asks for it."""
+    if flag is not None:
+        return KernelUse(flag, flag, flag)
+    if train:
+        return KernelUse(attention=False, wkv6=False, ssd=None)
+    on = True if use_flash else None
+    return KernelUse(on, on, on)
 
 
 def kernels_enabled(flag=None) -> bool:
@@ -119,12 +141,16 @@ def wkv6_apply(r, k, v, lw, u, state, *, use_chunked: bool = True,
 def ssd_apply(x, dt, a, b, c, state, *, use_chunked: bool = True,
               chunk: int = 128, compute_dtype=jnp.float32, use_kernels=None):
     """Mamba2 SSD scan on the nn layout (x (B,S,H,P), dt (B,S,H),
-    b/c (B,S,N), state (B,H,P,N)) — kernel or exact nn fallback."""
+    b/c (B,S,N), state (B,H,P,N)) — kernel or exact nn fallback.
+
+    The kernel takes the sequence when its chunks tile the kernel's blocks
+    (``kernels.ssd.tiles``), differentiated or not: under ``jax.grad`` it
+    contracts in one bfloat16 pass, what ``ssd_chunked`` compiles to at
+    either of its compute dtypes, float32 or bfloat16, and at HIGHEST
+    otherwise."""
     s = x.shape[1]
-    if kernels_enabled(use_kernels) and s % chunk == 0 and s > 1:
-        tr = lambda t: jnp.moveaxis(t, 1, 2)
-        y, new_state = ssd(tr(x), tr(dt), a, b, c, state, chunk=chunk)
-        return jnp.moveaxis(y, 2, 1), new_state
+    if kernels_enabled(use_kernels) and s > 1 and _ssd.tiles(s, chunk):
+        return ssd(x, dt, a, b, c, state, chunk=chunk)
     from repro.nn import mamba2 as _nn  # lazy: nn imports this module
     if use_chunked and s % chunk == 0 and s > 1:
         return _nn.ssd_chunked(x, dt, a, b, c, state, chunk=chunk,

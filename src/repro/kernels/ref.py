@@ -63,10 +63,6 @@ def wkv6_ref(r, k, v, lw, u, initial_state):
 
 
 def ssd_ref(x, dt, a, b, c, initial_state):
-    """(B,H,S,P) layout -> matches kernels.ssd.ssd outputs (fp32)."""
-    y, s = ssd_scan(jnp.moveaxis(x, 1, 2).astype(jnp.float32),
-                    jnp.moveaxis(dt, 1, 2).astype(jnp.float32),
-                    a.astype(jnp.float32),
-                    b.astype(jnp.float32), c.astype(jnp.float32),
-                    initial_state.astype(jnp.float32))
-    return jnp.moveaxis(y, 1, 2), s
+    """The nn layout (x (B,S,H,P)) in fp32 -> matches kernels.ssd.ssd."""
+    return ssd_scan(*(t.astype(jnp.float32)
+                      for t in (x, dt, a, b, c, initial_state)))

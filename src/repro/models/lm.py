@@ -134,7 +134,7 @@ def _attn_block_init(key, cfg: LMConfig, moe_layer: bool):
 
 
 def _attn_block_apply(p, cfg: LMConfig, h, positions, cache, cache_index,
-                      moe_layer: bool, use_kernels=False):
+                      moe_layer: bool, use_kernels):
     p = constrain_tree(p)  # pins param+cotangent shardings inside the scan
     y = rmsnorm_apply(p["attn_norm"], h, eps=cfg.norm_eps)
     if cfg.mla is not None:
@@ -149,7 +149,7 @@ def _attn_block_apply(p, cfg: LMConfig, h, positions, cache, cache_index,
             p["attn"], y, positions, num_heads=cfg.num_heads,
             num_kv_heads=cfg.num_kv_heads, head_dim=cfg.hd,
             rope_theta=cfg.rope_theta, cache=cache, cache_index=cache_index,
-            attn_fn=kernel_ops.attention_fn(use_kernels))
+            attn_fn=kernel_ops.attention_fn(use_kernels.attention))
     h = constrain(h + y, "F", "M", None)
     y = rmsnorm_apply(p["mlp_norm"], h, eps=cfg.norm_eps)
     if moe_layer:
@@ -172,7 +172,7 @@ def _rwkv_block_init(key, cfg: LMConfig):
     return p
 
 
-def _rwkv_block_apply(p, cfg: LMConfig, h, state, use_kernels=False):
+def _rwkv_block_apply(p, cfg: LMConfig, h, state, use_kernels):
     """state: {"wkv","tm_x","cm_x"} (decode) or None (fresh zeros)."""
     p = constrain_tree(p)
     b = h.shape[0]
@@ -190,7 +190,7 @@ def _rwkv_block_apply(p, cfg: LMConfig, h, state, use_kernels=False):
                                   use_chunked=cfg.use_chunked,
                                   chunk=min(cfg.ssm_chunk, 64),
                                   compute_dtype=jnp.dtype(cfg.ssm_compute_dtype),
-                                  use_kernels=use_kernels)
+                                  use_kernels=use_kernels.wkv6)
     h = constrain(h + y, "F", "M", None)
     x = layernorm_apply(p["ln2"], h)
     y, cm_x = channel_mix_apply(p["channel_mix"], x, state["cm_x"].astype(h.dtype))
@@ -207,7 +207,7 @@ def _mamba_layer_init(key, cfg: LMConfig):
                                        head_dim=cfg.ssm_head_dim)}
 
 
-def _mamba_mixer(p, cfg: LMConfig, x, state, use_kernels=False):
+def _mamba_mixer(p, cfg: LMConfig, x, state, use_kernels):
     """The Mamba-2 mixer on the normed input ``x``; a zero state (a fresh
     sequence) when ``state`` is None."""
     if state is None:
@@ -222,10 +222,10 @@ def _mamba_mixer(p, cfg: LMConfig, x, state, use_kernels=False):
             p, x, state, d_state=cfg.ssm_state, head_dim=cfg.ssm_head_dim,
             use_chunked=cfg.use_chunked, chunk=cfg.ssm_chunk,
             compute_dtype=jnp.dtype(cfg.ssm_compute_dtype),
-            norm_eps=cfg.norm_eps, use_kernels=use_kernels)
+            norm_eps=cfg.norm_eps, use_kernels=use_kernels.ssd)
 
 
-def _mamba_layer_apply(p, cfg: LMConfig, h, state, use_kernels=False):
+def _mamba_layer_apply(p, cfg: LMConfig, h, state, use_kernels):
     p = constrain_tree(p)
     y, new_state = _mamba_mixer(
         p["mamba"], cfg, rmsnorm_apply(p["norm"], h, eps=cfg.norm_eps),
@@ -260,7 +260,7 @@ def _scaled(y, multiplier: float):
 
 
 def _hybrid_layer_apply(p, cfg: LMConfig, mixer: str, h, positions, state,
-                        cache_index, use_kernels=False):
+                        cache_index, use_kernels):
     """One hybrid layer: ``h += r * mixer(rms(h))``, then ``h += r *
     mlp(rms(h))``, ``r`` the residual multiplier.  ``state`` is the
     mixer's decode state (a KV cache or the SSM and conv states)."""
@@ -275,7 +275,7 @@ def _hybrid_layer_apply(p, cfg: LMConfig, mixer: str, h, positions, state,
                 rope=cfg.position_embedding == "rope",
                 scale=cfg.attention_multiplier, cache=state,
                 cache_index=cache_index,
-                attn_fn=kernel_ops.attention_fn(use_kernels))
+                attn_fn=kernel_ops.attention_fn(use_kernels.attention))
     else:
         y, new_state = _mamba_mixer(p["mixer"], cfg, y, state, use_kernels)
     h = constrain(h + _scaled(y, cfg.residual_multiplier), "F", "M", None)
@@ -286,7 +286,7 @@ def _hybrid_layer_apply(p, cfg: LMConfig, mixer: str, h, positions, state,
 
 
 def _hybrid_period_apply(seg: Segment, p, state, cfg: LMConfig, h, positions,
-                         cache_index, remat: bool, use_kernels=False):
+                         cache_index, remat: bool, use_kernels):
     """The layers of one period in ``seg.pattern`` order, each taking the
     next layer of its mixer's stack.  With ``remat`` each layer is
     rematerialised on its own, so the backward pass holds the activations
@@ -363,7 +363,7 @@ def init_params(key, cfg: LMConfig):
 
 def _segment_forward(seg: Segment, seg_params, shared_p, cfg: LMConfig, h,
                      positions, seg_state, cache_index, train: bool,
-                     use_kernels=False):
+                     use_kernels):
     collect_state = seg_state is not None
 
     def body(h, xs):
@@ -451,12 +451,11 @@ def forward(params, cfg: LMConfig, batch, state=None, cache_index=None,
             jnp.arange(s, dtype=jnp.int32), (b, s))
 
     h = constrain(h, "F", "M", None)
-    # kernels/ops dispatch: "auto" (None) means kernels only on TPU and only
-    # for non-differentiated forwards — the Pallas kernels carry no custom
-    # VJPs, so training autodiff always takes the (bitwise-pinned) jnp path.
-    uk = cfg.use_kernels
-    if uk is None:
-        uk = False if train else (True if cfg.use_flash else None)
+    # kernels/ops dispatch, per kernel: "auto" (None) means kernels only on
+    # TPU; only the SSD kernel has a custom VJP, so a differentiated
+    # (training) forward keeps flash attention and wkv6 on the jnp path.
+    uk = kernel_ops.resolve_kernels(cfg.use_kernels, train=train,
+                                    use_flash=cfg.use_flash)
     aux_total = jnp.zeros((), jnp.float32)
     new_state = {} if state is not None else None
     for seg in layout(cfg):

@@ -269,6 +269,78 @@ def test_lm_forward_through_flash_kernel_matches_sdpa(seq):
                                **TOL[jnp.float32])
 
 
+# ------------------------------------------------ kernel dispatch in the LM
+@pytest.mark.parametrize("flag,train,use_flash,want", [
+    (None, True, False, ops.KernelUse(False, False, None)),
+    (None, True, True, ops.KernelUse(False, False, None)),
+    (None, False, False, ops.KernelUse(None, None, None)),
+    (None, False, True, ops.KernelUse(True, True, True)),
+    (False, True, False, ops.KernelUse(False, False, False)),
+    (True, False, False, ops.KernelUse(True, True, True)),
+])
+def test_resolve_kernels_per_kernel(flag, train, use_flash, want):
+    """Auto (None) keeps only ``ssd`` auto in training (flash attention and
+    wkv6 have no VJP); an explicit flag holds for every kernel."""
+    assert ops.resolve_kernels(flag, train=train, use_flash=use_flash) == want
+
+
+@pytest.mark.parametrize("s,chunk,routed", [
+    (256, 128, True), (64, 64, True), (64, 16, False), (192, 128, False)])
+def test_ssd_apply_routes_by_tiling(monkeypatch, s, chunk, routed):
+    """Where the backend is a TPU (here: the flag resolution's answer on
+    one), ``ssd_apply`` takes the kernel exactly where the chunks tile its
+    blocks: the chunk is the block's minor dim, so the whole sequence or a
+    multiple of 128."""
+    monkeypatch.setattr(ops, "kernels_enabled",
+                        lambda flag=None: True if flag is None else bool(flag))
+    h, p, n = 2, 8, 8
+    args = (jnp.zeros((1, s, h, p)), jnp.zeros((1, s, h)), jnp.zeros((h,)),
+            jnp.zeros((1, s, n)), jnp.zeros((1, s, n)),
+            jnp.zeros((1, h, p, n)))
+    text = str(jax.make_jaxpr(
+        lambda *t: ops.ssd_apply(*t, chunk=chunk))(*args))
+    assert ("pallas_call" in text) == routed
+
+
+def _granite_smoke():
+    cfg = get_config("granite_4_0_h_micro").smoke().replace(ssm_chunk=128)
+    params = L.init_params(jax.random.PRNGKey(0), cfg)
+    batch = {"tokens": jax.random.randint(KEY, (2, 256), 0, cfg.vocab_size)}
+    return cfg, params, batch
+
+
+def _lm_grad(cfg, params, batch):
+    return jax.jit(jax.grad(lambda p: L.lm_loss(p, cfg, batch)[0]))(params)
+
+
+def test_lm_training_on_cpu_is_bitwise_ssd_chunked():
+    """Off a TPU, auto (None) trains a hybrid stack through ``ssd_chunked``
+    exactly as an explicit False does."""
+    cfg, params, batch = _granite_smoke()
+    got = _lm_grad(cfg, params, batch)
+    want = _lm_grad(cfg.replace(use_kernels=False), params, batch)
+    for g, w in zip(jax.tree.leaves(got), jax.tree.leaves(want)):
+        np.testing.assert_array_equal(np.asarray(g), np.asarray(w))
+
+
+def test_lm_training_on_tpu_trains_through_ssd(monkeypatch):
+    """Where the backend is a TPU (here: the flag resolution's answer on
+    one), a differentiated forward takes the SSD kernel pair, forward and
+    backward, and keeps attention on ``sdpa``; its gradients match the jnp
+    path's."""
+    cfg, params, batch = _granite_smoke()
+    want = _lm_grad(cfg, params, batch)
+    monkeypatch.setattr(ops, "kernels_enabled",
+                        lambda flag=None: True if flag is None else bool(flag))
+    text = str(jax.make_jaxpr(jax.grad(
+        lambda p: L.lm_loss(p, cfg, batch)[0]))(params))
+    assert "ssd_bwd" in text and "flash_attention" not in text
+    got = _lm_grad(cfg, params, batch)
+    for g, w in zip(jax.tree.leaves(got), jax.tree.leaves(want)):
+        np.testing.assert_allclose(np.asarray(g), np.asarray(w),
+                                   rtol=1e-4, atol=1e-6)
+
+
 # ------------------------------------------- population-batched applies
 def test_pop_linear_jnp_fallback_bitwise_vs_vmap():
     """fused=False lowers to the same dot_general as vmap of the member
@@ -360,8 +432,8 @@ def test_wkv6_sweep(b, h, s, d, chunk, dtype):
 @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
 def test_ssd_sweep(b, h, s, p, n, chunk, dtype):
     ks = jax.random.split(KEY, 6)
-    x = jax.random.normal(ks[0], (b, h, s, p), dtype)
-    dt = jax.nn.softplus(jax.random.normal(ks[1], (b, h, s)))
+    x = jax.random.normal(ks[0], (b, s, h, p), dtype)
+    dt = jax.nn.softplus(jax.random.normal(ks[1], (b, s, h)))
     a = -jnp.exp(jax.random.normal(ks[2], (h,)) * 0.3)
     bb = jax.random.normal(ks[3], (b, s, n), dtype)
     cc = jax.random.normal(ks[4], (b, s, n), dtype)
@@ -380,8 +452,8 @@ def test_ssd_long_chunk_against_float64():
     here against the scan run in float64."""
     b, h, s, p, n, chunk = 1, 2, 512, 64, 64, 256
     ks = jax.random.split(KEY, 6)
-    x = jax.random.normal(ks[0], (b, h, s, p))
-    dt = jax.nn.softplus(jax.random.normal(ks[1], (b, h, s)))
+    x = jax.random.normal(ks[0], (b, s, h, p))
+    dt = jax.nn.softplus(jax.random.normal(ks[1], (b, s, h)))
     a = -jnp.exp(jax.random.normal(ks[2], (h,)) * 0.3)
     bb = jax.random.normal(ks[3], (b, s, n))
     cc = jax.random.normal(ks[4], (b, s, n))
@@ -389,14 +461,52 @@ def test_ssd_long_chunk_against_float64():
     y, sf = ops.ssd(x, dt, a, bb, cc, h0, chunk=chunk, interpret=True)
     with jax.enable_x64(True):
         f64 = [np.asarray(t, np.float64) for t in (x, dt, a, bb, cc, h0)]
-        yr, sr = ref.ssd_scan(np.moveaxis(f64[0], 1, 2),
-                              np.moveaxis(f64[1], 1, 2), *f64[2:])
-        yr, sr = np.moveaxis(np.asarray(yr), 1, 2), np.asarray(sr)
+        yr, sr = (np.asarray(t) for t in ref.ssd_scan(*f64))
     assert yr.dtype == np.float64
     # absolute: |y| reaches ~100, where a relative 2e-4 would hide the
     # ~1e-3 a plainly rounded cumsum costs
     np.testing.assert_allclose(np.asarray(y), yr, atol=2e-4, rtol=0)
     np.testing.assert_allclose(np.asarray(sf), sr, atol=2e-4, rtol=0)
+
+
+@pytest.mark.parametrize("b,h,s,p,n,chunk", [
+    (1, 2, 64, 8, 4, 16),      # four chunks
+    (2, 3, 96, 16, 8, 32),     # a batch; heads not a multiple of 8
+    (1, 16, 64, 8, 8, 16),     # two groups of 8 heads share B and C
+    (1, 2, 512, 16, 16, 256),  # long chunks: the segsum's rounding
+])
+def test_ssd_grad_against_float64(b, h, s, p, n, chunk):
+    """The kernel pair's custom VJP against ``jax.grad`` of the literal
+    scan in float64, for every input and a non-zero initial state, with
+    cotangents on both outputs.  Each gradient's largest error is bounded
+    relative to its largest value; ``a``'s more loosely, since it sums the
+    per-position terms of both sides of each segsum, which cancel (at
+    chunk 256 float32 leaves 1.9e-4 of it here, ``ssd_chunked`` 7.3e-5)."""
+    ks = jax.random.split(KEY, 8)
+    args = (jax.random.normal(ks[0], (b, s, h, p)),
+            jax.nn.softplus(jax.random.normal(ks[1], (b, s, h))),
+            -jnp.exp(jax.random.normal(ks[2], (h,)) * 0.3),
+            jax.random.normal(ks[3], (b, s, n)),
+            jax.random.normal(ks[4], (b, s, n)),
+            jax.random.normal(ks[5], (b, h, p, n)) * 0.1)
+    wy = np.asarray(jax.random.normal(ks[6], (b, s, h, p)))
+    ws = np.asarray(jax.random.normal(ks[7], (b, h, p, n)))
+
+    def loss(scan):
+        def f(*args):
+            y, sf = scan(*args)
+            return jnp.sum(y * wy) + jnp.sum(sf * ws)
+        return jax.grad(f, argnums=range(6))
+
+    got = loss(lambda *t: ops.ssd(*t, chunk=chunk, interpret=True))(*args)
+    with jax.enable_x64(True):
+        want = loss(ref.ssd_scan)(*(np.asarray(t, np.float64) for t in args))
+        want = [np.asarray(g) for g in want]
+    for name, g, w in zip(("x", "dt", "a", "b", "c", "state"), got, want):
+        assert w.dtype == np.float64 and g.shape == w.shape
+        err = np.abs(np.asarray(g, np.float64) - w).max()
+        bound = 1e-3 if name == "a" else 5e-5
+        assert err <= bound * np.abs(w).max(), (name, err, np.abs(w).max())
 
 
 def test_grad_accum_equivalence():

@@ -116,14 +116,38 @@ def test_wkv6_compiles_for_v5e(one_chip):
     _assert_kernel(text, "wkv6")
 
 
+def _ssd_shapes(cfg, s, h):
+    """x, dt, a, b, c and the state on the nn layout, float32."""
+    p, n, f32 = cfg.ssm_head_dim, cfg.ssm_state, jnp.float32
+    return (((1, s, h, p), f32), ((1, s, h), f32), ((h,), f32),
+            ((1, s, n), f32), ((1, s, n), f32), ((1, h, p, n), f32))
+
+
 def test_ssd_compiles_for_v5e(one_chip):
     cfg = get_config("zamba2_7b")
-    p, n, s, f32 = cfg.ssm_head_dim, cfg.ssm_state, 1024, jnp.float32
     h = 8                      # heads only repeat the grid; 8 of the 112
     text = _compile(
         lambda x, dt, a, b, c, s0: sd.ssd(x, dt, a, b, c, s0,
                                           chunk=cfg.ssm_chunk),
-        one_chip, ((1, h, s, p), f32), ((1, h, s), f32), ((h,), f32),
-        ((1, s, n), f32), ((1, s, n), f32), ((1, h, p, n), f32))
+        one_chip, *_ssd_shapes(cfg, 1024, h))
     _assert_kernel(text, "ssd")
+
+
+def test_ssd_grad_compiles_for_v5e(one_chip):
+    """``jax.grad`` through the kernel pair at granite's widths (P 64, N
+    128, chunk 256; 8 of its 64 heads) holds the forward, which writes the
+    residual states, and the backward as kernels."""
+    cfg = get_config("granite_4_0_h_micro")
+
+    def loss(*args):
+        y, sf = sd.ssd(*args, chunk=cfg.ssm_chunk)
+        return jnp.sum(y * y) + jnp.sum(sf)
+
+    text = _compile(jax.grad(loss, argnums=range(6)), one_chip,
+                    *_ssd_shapes(cfg, 1024, 8))
+    calls = [line for line in text.splitlines()
+             if 'custom_call_target="tpu_custom_call"' in line]
+    assert sum("ssd_bwd" in line for line in calls) == 1
+    assert sum("ssd" in line and "ssd_bwd" not in line
+               for line in calls) == 1
 
